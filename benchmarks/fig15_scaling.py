@@ -1,100 +1,79 @@
 """Fig. 15 — multi-device scalability of the walk engine.
 
-Queries are hash-partitioned over devices (the paper's §6.6 scheme) with
-the graph replicated per device; walks run under shard_map.  This host has
-ONE physical core, so the subprocess forces N host devices and we report
-the *work-distribution* quality (per-device query counts and the sharded
-engine's consistency), plus wall time (flat on 1 core; linear on real
-hardware — noted in the derived column).
+One process sweeps ``jax.devices()[:n]`` for every device count the host
+has (1, 2, 4, … up to ``len(jax.devices())``): the slot pool shards over a
+1D walker mesh of the first ``n`` devices and the graph is replicated per
+device (docs/scaling.md).  No child processes — a chip belongs to the one
+process that opened it.  On a CPU host, give the process several devices
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before
+launching; their times then say nothing about a chip.
 
 Two rows per device count:
 
-* ``fig15/devices{n}``       — ``walk_batch`` on a pre-sharded batch (the
-  fully-occupied, no-host-scheduling path);
+* ``fig15/devices{n}``       — ``walk_batch(devices=n)`` on one fully
+  occupied batch (no host scheduling);
 * ``fig15/sched_devices{n}`` — the *sharded streaming scheduler*
-  (``run(devices=n)``, docs/scaling.md): slot pool at half the query
-  count, so every device takes mid-walk refills from the host queue.
-  ``ident`` reports whether its paths matched the single-device
-  scheduler bit-for-bit (the topology-invariance guarantee).
+  (``run(devices=n)``): slot pool at half the query count, so every
+  device takes mid-walk refills from the host queue.
+
+Both must match the single-device run bit for bit (the topology-
+invariance guarantee); a mismatch raises, so a failed phase exits
+non-zero instead of printing a row.
 """
-import json
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import numpy as np
 
 from benchmarks.common import emit
-
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={NDEV}"
-import time, json
-import jax, jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import EngineConfig, WalkEngine
 from repro.graphs import power_law_graph
 from repro.walks import node2vec
-from repro.core import WalkEngine, EngineConfig
 
-n_dev = len(jax.devices())
-g = power_law_graph(2000, 12, weight_dist="uniform", seed=1)
-eng = WalkEngine(g, node2vec(), EngineConfig(method="ervs", tile=128))
-Q = 512
-starts = np.arange(Q, dtype=np.int32)
-# hash-partition queries over devices (paper §6.6)
-dev_of = starts % n_dev
-order = np.argsort(dev_of, kind="stable")
-starts_p = starts[order]
-mesh = jax.make_mesh((n_dev,), ("data",))
-sh = NamedSharding(mesh, P("data"))
-sharded_starts = jax.device_put(jnp.asarray(starts_p), sh)
-key = jax.random.key(0)
-path, _ = eng.walk_batch(sharded_starts, key, 10)
-jax.block_until_ready(path)
-t0 = time.perf_counter()
-path, _ = eng.walk_batch(sharded_starts, key, 10)
-jax.block_until_ready(path)
-dt = time.perf_counter() - t0
-counts = np.bincount(dev_of, minlength=n_dev).tolist()
-ok = bool((np.asarray(path) >= 0).all())
+Q, STEPS = 512, 10
 
-# sharded streaming scheduler: half-size slot pool forces host refills
-devs = n_dev if n_dev > 1 else None
-res = eng.run(starts, num_steps=10, key=key, batch=Q // 2, epoch_len=4,
-              devices=devs)  # warm (compile)
-t0 = time.perf_counter()
-res = eng.run(starts, num_steps=10, key=key, batch=Q // 2, epoch_len=4,
-              devices=devs)
-sched_dt = time.perf_counter() - t0
-ref = eng.run(starts, num_steps=10, key=key, batch=Q // 2, epoch_len=4)
-ident = bool((res.paths == ref.paths).all())
-sched_counts = ([d["queries"] for d in res.per_device]
-                if res.per_device else [Q])
-print(json.dumps({"n_dev": n_dev, "secs": dt, "counts": counts, "ok": ok,
-                  "sched_secs": sched_dt, "sched_counts": sched_counts,
-                  "ident": ident}))
-"""
+
+def _timed(fn):
+    fn()  # warm (compile)
+    t0 = time.perf_counter()
+    out = fn()
+    return out, time.perf_counter() - t0
 
 
 def main(quick: bool = False):
-    for n in ([1, 4] if quick else [1, 2, 4, 8]):
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD.replace("{NDEV}", str(n))],
-            capture_output=True, text=True,
-            env={**os.environ, "PYTHONPATH": "src"})
-        line = out.stdout.strip().splitlines()[-1] if out.stdout else "{}"
-        try:
-            rec = json.loads(line)
-        except json.JSONDecodeError:
-            emit(f"fig15/devices{n}", -1, "FAIL:" + out.stderr[-200:])
-            continue
-        balance = (min(rec["counts"]) / max(rec["counts"])
-                   if max(rec["counts"]) else 0)
-        emit(f"fig15/devices{n}", rec["secs"] * 1e6,
-             f"ok={rec['ok']};balance={balance:.2f};1-core-host")
-        sbal = (min(rec["sched_counts"]) / max(rec["sched_counts"])
-                if max(rec["sched_counts"]) else 0)
-        emit(f"fig15/sched_devices{n}", rec["sched_secs"] * 1e6,
-             f"ident={rec['ident']};balance={sbal:.2f};1-core-host")
+    g = power_law_graph(2000, 12, weight_dist="uniform", seed=1)
+    eng = WalkEngine(g, node2vec(), EngineConfig(method="ervs", tile=128))
+    starts = np.arange(Q, dtype=np.int32)
+    key = jax.random.key(0)
+    n_max = len(jax.devices())
+    counts = [n for n in (1, 2, 4, 8) if n <= n_max]
+    if quick:
+        counts = sorted({1, counts[-1]})
+    ref_batch = ref_sched = None
+    for n in counts:
+        def batch():
+            path, _ = eng.walk_batch(starts, key, STEPS, devices=n)
+            return np.asarray(jax.block_until_ready(path))
+
+        def sched():
+            return eng.run(starts, num_steps=STEPS, key=key, batch=Q // 2,
+                           epoch_len=4, devices=n)
+
+        path, dt = _timed(batch)
+        res, sched_dt = _timed(sched)
+        if ref_batch is None:
+            ref_batch, ref_sched = path, res.paths
+        if not (np.array_equal(path, ref_batch)
+                and np.array_equal(res.paths, ref_sched)):
+            raise AssertionError(
+                f"devices={n}: paths differ from the single-device run")
+        dev_q = ([d["queries"] for d in res.per_device]
+                 if res.per_device else [Q])
+        balance = min(dev_q) / max(dev_q)
+        where = f"{jax.devices()[0].platform}x{n}"
+        emit(f"fig15/devices{n}", dt * 1e6, f"ident=True;{where}")
+        emit(f"fig15/sched_devices{n}", sched_dt * 1e6,
+             f"ident=True;balance={balance:.2f};{where}")
 
 
 if __name__ == "__main__":

@@ -15,7 +15,7 @@ import time  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import EngineConfig, WalkEngine  # noqa: E402
 from repro.graphs import power_law_graph  # noqa: E402
@@ -34,7 +34,8 @@ def main():
     # scales worse because node ids correlate with degree)
     dev_of = starts % len(devs)
     order = np.argsort(dev_of, kind="stable")
-    mesh = jax.make_mesh((len(devs),), ("data",))
+    mesh = jax.make_mesh((len(devs),), ("data",),
+                         axis_types=(AxisType.Auto,))
     sharded = jax.device_put(jnp.asarray(starts[order]),
                              NamedSharding(mesh, P("data")))
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.core.ctxutil import degrees_of, single_edge_ctx
 from repro.core.types import Workload
 from repro.graphs.csr import CSRGraph
+from repro.kernels.prng import threefry_seeds, trial_uniform
 
 
 @partial(jax.jit, static_argnames=("workload", "params", "trials_per_round", "max_rounds"))
@@ -99,6 +100,7 @@ def erjs_step(
 
 
 def _fold_uniform(rng: jax.Array, counter, W: int) -> jax.Array:
-    keys = jax.vmap(lambda k: jax.random.fold_in(k, counter))(rng)
-    return jax.vmap(lambda k: jax.random.uniform(
-        k, (), dtype=jnp.float32, minval=1e-12, maxval=1.0))(keys)
+    """Uniform number ``counter`` of each walker's step stream — the
+    ``prng.trial_uniform`` the fused kernel draws per lane."""
+    seeds = threefry_seeds(rng)
+    return trial_uniform(seeds[:, 0], seeds[:, 1], counter)

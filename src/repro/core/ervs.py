@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.core.ctxutil import degrees_of as degrees_of_cached, eval_weights, tile_ctx
 from repro.core.types import Workload
 from repro.graphs.csr import CSRGraph
+from repro.kernels.prng import threefry_seeds, tile_uniforms
 
 NEG_INF = jnp.float32(-jnp.inf)
 
@@ -176,14 +177,14 @@ def ervs_jump_step(
 
 
 def _tile_uniforms(rng: jax.Array, t, shape) -> jax.Array:
-    """Counter-based uniforms for (walker-batch, tile t): fold t into the key.
+    """Counter-based uniforms for (walker-batch, tile t): counter (t, j).
 
-    rng is [W, 2] (one key per walker); we fold the tile counter so that any
-    tile's randomness is addressable without advancing a stream — this is
-    what makes block-level jumps actually free in the Pallas kernel.
+    rng is [W] per-walker keys; any tile's randomness is addressable
+    without advancing a stream — this is what makes block-level jumps
+    actually free in the Pallas kernel, which calls the same
+    ``prng.tile_uniforms`` per lane (bit-identical by construction).
     """
     W, tile = shape
-    base = jax.vmap(lambda k: jax.random.fold_in(k, t))(rng)
-    u = jax.vmap(lambda k: jax.random.uniform(
-        k, (tile,), dtype=jnp.float32, minval=1e-12, maxval=1.0))(base)
-    return u
+    seeds = threefry_seeds(rng)
+    j = jnp.arange(tile, dtype=jnp.uint32)[None, :]
+    return tile_uniforms(seeds[:, :1], seeds[:, 1:], t, j)

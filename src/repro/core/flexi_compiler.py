@@ -692,7 +692,12 @@ def fuse_report(workload: Workload) -> FuseReport:
                 f"{sorted(taint & FUSE_BOUND_STATE)} — no baked per-node "
                 f"bound; rejection stays staged")
 
-    hooks_fusable = True
+    from repro.kernels.megastep_kernel import wstate_refusal
+    why = wstate_refusal(jax.tree_util.tree_leaves(
+        jax.eval_shape(workload.wstate_template)))
+    hooks_fusable = why is None
+    if why is not None:
+        reasons.append(why)
     if workload.has_hooks:
         params = workload.params()
         template_ws = workload.wstate_template()

@@ -52,24 +52,12 @@ from repro.core.ctxutil import degrees_of
 from repro.core.types import EdgeCtx, Workload
 from repro.graphs.csr import CSRGraph
 from repro.graphs.delta import host_row_layout
-from repro.kernels.prng import uniform_01, uniform_pair_01
-
-# Threefry counter salts (shared with kernels/precomp_kernel.py and the
-# kernels/ref.py oracles) so table draws never collide with the uniforms
+# Threefry counter salts and the key derivation live in kernels/prng.py,
+# shared with kernels/precomp_kernel.py, the mega-step kernel and the
+# kernels/ref.py oracles, so table draws never collide with the uniforms
 # any other sampler derives from the same per-(walker, step) stream key.
-ITS_SALT = 0x175CDF
-ALIAS_SALT = 0xA11A5
-
-
-def threefry_seeds(rng: jax.Array) -> jax.Array:
-    """[W] typed per-(walker, step) keys → [W, 2] uint32 Threefry pairs.
-
-    The single derivation both the jnp selectors below and the Pallas
-    kernel path consume — sharing it (plus the salts) is what makes the
-    two ``precomp_exec`` paths bit-identical.
-    """
-    data = jax.random.key_data(rng)
-    return jnp.asarray(data, jnp.uint32).reshape(data.shape[0], -1)[:, :2]
+from repro.kernels.prng import (ALIAS_SALT, ITS_SALT, threefry_seeds,
+                                uniform_01, uniform_pair_01)
 
 
 @jax.tree_util.register_dataclass

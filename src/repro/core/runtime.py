@@ -421,9 +421,12 @@ class EpochScheduler:
         ``track_tables=True``; call it directly to make a just-drained
         repair visible mid-run."""
         eng = self.engine
-        self.tables = eng.precomp
-        self.graph_view = eng.graph
-        self.stats_view = eng.stats
+        if self.mesh is None:
+            self.tables, self.graph_view, self.stats_view = (
+                eng.precomp, eng.graph, eng.stats)
+        else:
+            self.tables, self.graph_view, self.stats_view = (
+                eng.replicated_views(self.mesh))
         self.pad_view = eng.pad
         self.max_tiles_view = eng.max_tiles
         self._mutation_seen = eng.mutation_clock
@@ -617,6 +620,9 @@ class WalkEngine:
         # (tests/test_structural.py) pins mutation bursts to O(log K)
         self.staged_traces = 0
         self.fused_traces = 0
+        # walker mesh -> {view name: (source, replicated copy)}, see
+        # replicated_views
+        self._replicas: dict = {}
         # Both epochs are jitted ONCE per engine: everything a mutation
         # changes (graph, stats, tables, edge streams) enters as a
         # runtime argument, so a mutation retraces only when an argument
@@ -863,6 +869,22 @@ class WalkEngine:
                               epoch_len=epoch_len, num_steps=num_steps,
                               pad=pad, max_tiles=max_tiles)
 
+    def replicated_views(self, mesh):
+        """(tables, graph, stats) with one copy on every device of
+        ``mesh``, so sharded epochs read a local copy instead of pulling
+        operands from device 0.  Each view is broadcast once per mesh and
+        again only after a mutation or compaction swaps it — not per
+        walk_batch call or per scheduler epoch."""
+        old = self._replicas.get(mesh, {})
+        new = {}
+        for name, src in (("tables", self.precomp), ("graph", self.graph),
+                          ("stats", self.stats)):
+            hit = old.get(name)
+            new[name] = (src, hit[1] if hit is not None and hit[0] is src
+                         else shd.replicate(src, mesh))
+        self._replicas[mesh] = new
+        return tuple(view for _, view in new.values())
+
     # ------------------------------------------------------------ frontend
     def run(self, starts, num_steps: Optional[int] = None,
             key: Optional[jax.Array] = None, batch: Optional[int] = None,
@@ -1080,9 +1102,13 @@ class WalkEngine:
                 raise ValueError(
                     f"devices={devices} must divide the batch ({W}); pad "
                     f"the batch or use run(), which pads its slot pool")
-            state = shd.shard_walker_state(state, W, shd.walker_mesh(devices))
+            mesh = shd.walker_mesh(devices)
+            state = shd.shard_walker_state(state, W, mesh)
+            tables, graph, stats = self.replicated_views(mesh)
+        else:
+            tables, graph, stats = self.precomp, self.graph, self.stats
         _, emitted, stats = self.run_epoch_fn(
-            state, self.precomp, self.graph, self.stats,
+            state, tables, graph, stats,
             epoch_len=num_steps, num_steps=num_steps, pad=self.pad,
             max_tiles=self.max_tiles,
             fused=(devices is None or devices <= 1))

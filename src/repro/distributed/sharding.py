@@ -22,7 +22,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # ----------------------------------------------------------------- rules
 
@@ -108,12 +108,28 @@ def activation_sharding_ctx(mesh: Mesh, logical: Optional[Dict] = None,
 def walker_mesh(num_devices: Optional[int] = None) -> Mesh:
     """A 1D mesh over ``num_devices`` (default: all local devices) whose
     single axis is named ``"walkers"`` — the axis ``DEFAULT_LOGICAL_RULES``
-    maps the slot-pool batch dim onto."""
+    maps the slot-pool batch dim onto.
+
+    The axis is ``Auto`` (GSPMD-propagated): the scheduler's host refills
+    (``.at[idx].set`` on sharded leaves) and other eager ops must accept
+    walker-sharded operands without entering a mesh context, which
+    ``Explicit`` axes (``jax.make_mesh``'s default) refuse."""
     devs = jax.devices()
     n = len(devs) if num_devices is None else int(num_devices)
     if not 1 <= n <= len(devs):
         raise ValueError(f"num_devices must be in [1, {len(devs)}], got {n}")
-    return jax.make_mesh((n,), ("walkers",), devices=devs[:n])
+    return jax.make_mesh((n,), ("walkers",), devices=devs[:n],
+                         axis_types=(AxisType.Auto,))
+
+
+def replicate(tree, mesh: Mesh):
+    """Place every array leaf of ``tree`` (graph, node stats, precomp
+    tables) on every device of ``mesh`` — once, so sharded epochs read a
+    local copy instead of pulling operands from device 0."""
+    rep = NamedSharding(mesh, P())
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.device_put(leaf, rep)
+        if isinstance(leaf, jax.Array) else leaf, tree)
 
 
 def walker_rules(mesh: Mesh) -> MeshRules:

@@ -1,9 +1,10 @@
 """Fused mega-step Pallas kernel — one kernel per scheduler epoch.
 
-One grid step per walker *lane* runs the ENTIRE per-step chain for
+Each grid step takes a block of walker *lanes* (``precomp_kernel.
+walker_grid``) and runs, lane after lane, the ENTIRE per-step chain for
 ``epoch_len`` consecutive walk steps without returning to XLA between
 stages (ThunderRW's gather-move-update interleaving; C-SAW's
-warp-per-walker structure, with warps → grid lanes):
+warp-per-walker structure, with warps → lanes of a block):
 
   neighbour-tile DMA from the tile-aligned CSR stream
     → WalkProgram weight evaluation (programs the Flexi-Compiler proves
@@ -14,18 +15,17 @@ warp-per-walker structure, with warps → grid lanes):
 
 Bit-identity contract (tests/test_megastep.py, tests/test_conformance.py)
 -------------------------------------------------------------------------
-The kernel consumes the SAME counter-based Threefry triples as the staged
-scan (``kernels/prng.py``; per-step key = ``threefry2x32(rng, 0, step)``
-= ``WalkerState.stream_keys()``), replicates the staged float maps
-exactly (``jax.random.uniform(minval=1e-12)`` bit pattern for the
-eRVS/eRJS draws, the top-24-bit map of ``prng.uniform_01`` for table
-draws with the shared ITS/ALIAS salts), and applies the same masks in
-the same order — so for every fusable (sampler × program) cell
+The kernel calls the SAME counter-based Threefry functions as the staged
+scan (``kernels/prng.py``: per-step key = ``threefry2x32(rng, 0, step)``
+= ``WalkerState.stream_keys()``, ``tile_uniforms`` for eRVS keys,
+``trial_uniform`` for eRJS trials, ``uniform_01``/``uniform_pair_01``
+with the shared ITS/ALIAS salts for table draws), and applies the same
+masks in the same order — so for every fusable (sampler × program) cell
 ``step_exec=fused`` produces byte-identical paths AND telemetry to
 ``step_exec=staged``.  That makes the staged scan a true fallback, not a
 different estimator.
 
-Per-step telemetry is accumulated as a per-(lane, step) int32 flag word
+Per-step telemetry is accumulated as a per-(step, lane) int32 flag word
 (bit positions = ``StepStats.LIVE`` …) and reduced to ``StepStats``
 outside the kernel — integer sums, so the reduction is order-free exact.
 
@@ -33,7 +33,10 @@ Layout: edge streams are ``ops.align_rows`` [R, 128] tiles (every row
 starts on a lane boundary; ≥2 slack sublane-rows so a trailing DMA never
 reads out of bounds); per-node scalars ride ``pack_node_stream`` [V→pad,
 128] streams so in-kernel degree/row0/bound/total lookups are one (8,
-128) DMA each.  ``default_interpret()`` gates compiled vs interpret mode
+128) DMA each (``precomp_kernel.read_elem``).  Per-lane state (cur, prev,
+step, alive, key words, scalar wstate leaves) rides (B,) SMEM windows;
+the [T, W] emitted/flag outputs are (T, B) VMEM blocks written one step
+row at a time.  ``default_interpret()`` gates compiled vs interpret mode
 exactly like the precomp kernels.
 """
 from __future__ import annotations
@@ -49,9 +52,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.types import EdgeCtx, StepStats, WalkerState
 from repro.graphs.delta import host_row_layout
 from repro.kernels.ops import align_rows_layout
-from repro.kernels.precomp_kernel import (ALIAS_SALT, ITS_SALT,
-                                          default_interpret)
-from repro.kernels.prng import threefry2x32, uniform_01, uniform_pair_01
+from repro.kernels.precomp_kernel import (default_interpret, dma_tiles,
+                                          lane_pick, pad_lanes, read_elem,
+                                          read_elems, smem_lanes,
+                                          walker_grid)
+from repro.kernels.prng import (ALIAS_SALT, ITS_SALT, threefry2x32,
+                                threefry_seeds, tile_uniforms, trial_uniform,
+                                uniform_01, uniform_pair_01)
 from repro.kernels.ref import LANES, SUBLANES, TILE
 
 #: regime kinds a sampler may declare fusable (``Sampler.fused_kind``)
@@ -62,15 +69,24 @@ FUSED_KINDS = ("reservoir", "rejection", "precomp_its", "precomp_alias")
 _NEG_INF = np.float32(-np.inf)
 
 
+def wstate_refusal(leaves, lane_shape=()) -> Optional[str]:
+    """Why the kernel cannot hold these wstate leaves, or None.  It keeps
+    each leaf as one 32-bit scalar per lane in a (B,) SMEM window, so
+    every leaf must have shape ``lane_shape`` — ``()`` for the per-walker
+    template (``fuse_report``), ``(W,)`` for a batched state."""
+    for leaf in leaves:
+        if leaf.shape != tuple(lane_shape) or leaf.dtype.itemsize > 4:
+            return (f"wstate leaf {leaf.shape}/{leaf.dtype} is not a 32-bit "
+                    f"scalar per lane — the kernel keeps per-lane state in "
+                    f"scalar memory")
+    return None
+
+
 def _log_keys(u, w):
     """Bit-exact replica of ``ervs._log_keys`` (ln(u)/w̃, -inf for w̃≤0)."""
     safe_w = jnp.where(w > 0, w, 1.0)
     lk = jnp.log(u) / safe_w
     return jnp.where(w > 0, lk, _NEG_INF)
-
-# extra edge streams each kind consumes beyond (deg, row0, nbr, h)
-_EXTRA_STREAMS = {"reservoir": 0, "rejection": 1,
-                  "precomp_its": 3, "precomp_alias": 4}
 
 
 def pack_node_stream(x) -> jnp.ndarray:
@@ -86,95 +102,57 @@ def pack_node_stream(x) -> jnp.ndarray:
     return flat.reshape(pad // LANES, LANES)
 
 
-def _iota(n: int, dtype=jnp.int32):
-    # ≥2D iota only (TPU restriction); squeeze back to the vector
-    return jax.lax.broadcasted_iota(dtype, (n, 1), 0)[:, 0]
-
-
-# --------------------------------------------------------------- DMA reads
-def _dma_block(hbm, buf, sem, row):
-    """Copy the (8, 128) tile starting at sublane-row ``row`` into VMEM
-    and return it flattened to [TILE]."""
-    cp = pltpu.make_async_copy(hbm.at[pl.ds(row, SUBLANES), :], buf, sem)
-    cp.start()
-    cp.wait()
-    return buf[...].reshape(TILE)
-
-
-def _read_elem(hbm, buf, sem, r0, pos):
-    """Element ``pos`` of the row starting at sublane-row ``r0``."""
-    blk = pos // TILE
-    return _dma_block(hbm, buf, sem, r0 + blk * SUBLANES)[pos - blk * TILE]
-
-
-def _read_span(hbm, buf, sem, r0, start, n: int):
-    """``n`` consecutive elements from offset ``start`` (static ``n``
-    dividing TILE, ``start`` a multiple of ``n`` — the span never crosses
-    a TILE boundary)."""
+def _span(r0, start, tile: int, *streams):
+    """The ``tile`` elements from offset ``start`` (a multiple of
+    ``tile``, which divides TILE — the span never crosses a DMA tile) of
+    the row at sublane-row ``r0`` in each ``(hbm, buf, sem)`` stream.
+    Returns ``(j, *vals)``, all (max(tile/128, 1), 128): ``j`` is each
+    lane's offset within the span, in [0, tile) on the span's lanes and
+    outside it on the rest of a shared row."""
     blk = start // TILE
-    flat = _dma_block(hbm, buf, sem, r0 + blk * SUBLANES)
-    return jax.lax.dynamic_slice(flat, (start - blk * TILE,), (n,))
-
-
-# ----------------------------------------------------- staged-RNG replicas
-def _tile_uniforms_lane(sk0, sk1, t, tile: int):
-    """Bit-exact per-lane replica of ``ervs._tile_uniforms(rng, t)[lane]``:
-    fold the tile counter into the per-step key, then the jax threefry
-    even-size counter split + (1e-12, 1.0) float map."""
-    fk0, fk1 = threefry2x32(sk0, sk1, jnp.uint32(0), t)
-    half = tile // 2
-    c0 = _iota(half, jnp.uint32)
-    r0, r1 = threefry2x32(fk0, fk1, c0, c0 + jnp.uint32(half))
-    bits = jnp.concatenate([r0, r1])
-    return _uniform_map(bits)
-
-
-def _uniform_scalar_lane(sk0, sk1, c):
-    """Bit-exact per-lane replica of ``erjs._fold_uniform(rng, c)[lane]``
-    (jax's shape-() draw odd-pads the counter to (0, 0) and keeps r0)."""
-    gk0, gk1 = threefry2x32(sk0, sk1, jnp.uint32(0), c)
-    bits, _ = threefry2x32(gk0, gk1, jnp.uint32(0), jnp.uint32(0))
-    return _uniform_map(bits)
-
-
-def _uniform_map(bits):
-    """jax.random.uniform's bits→float map with (minval, maxval) =
-    (1e-12, 1.0), replicated operation by operation."""
-    f = jax.lax.bitcast_convert_type(
-        (bits >> jnp.uint32(9)) | jnp.uint32(0x3F800000), jnp.float32) - 1.0
-    eps = jnp.float32(1e-12)
-    return jnp.maximum(eps, f * (jnp.float32(1.0) - eps) + eps)
+    dma_tiles(r0 + blk * SUBLANES, *streams)
+    within = start - blk * TILE
+    nr = max(tile // LANES, 1)
+    j = (jax.lax.broadcasted_iota(jnp.int32, (nr, LANES), 0) * LANES
+         + jax.lax.broadcasted_iota(jnp.int32, (nr, LANES), 1)
+         - within % LANES)
+    return (j,) + tuple(buf[pl.ds(within // LANES, nr), :]
+                        for _, buf, _ in streams)
 
 
 # ------------------------------------------------------------------ kernel
 def _make_kernel(program, params, *, kind: str, tile: int, max_tiles: int,
                  rjs_trials: int, rjs_max_rounds: int, epoch_len: int,
-                 num_steps: int, n_streams: int, n_ws: int, ws_treedef):
+                 num_steps: int, n_streams: int, ws_dtypes, ws_treedef):
     """Build the mega-step kernel body (refs sliced positionally)."""
     K, R = rjs_trials, rjs_max_rounds
     LIVE, RJS = StepStats.LIVE, StepStats.RJS
     FALLBACK, PRECOMP, STALE = (StepStats.FALLBACK, StepStats.PRECOMP,
                                 StepStats.STALE)
+    n_ws = len(ws_dtypes)
+    # per-candidate weights: the staged eval_weights' map over edges with
+    # the walker's params/wstate broadcast, once per (row, lane) axis
+    tile_weight = jax.vmap(jax.vmap(program.edge_weight,
+                                    in_axes=(0, None, None)),
+                           in_axes=(0, None, None))
 
     def kernel(*refs):
-        cur_s, prev_s, step_s, alive_s, seed_s = refs[:5]
-        streams = refs[5:5 + n_streams]
-        ws_refs = refs[5 + n_streams:5 + n_streams + n_ws]
-        k = 5 + n_streams + n_ws
+        cur_s, prev_s, step_s, alive_s, k0_s, k1_s = refs[:6]
+        streams = refs[6:6 + n_streams]
+        ws_refs = refs[6 + n_streams:6 + n_streams + n_ws]
+        k = 6 + n_streams + n_ws
         em_ref, fl_ref, ocur, oprev, ostep, oalive = refs[k:k + 6]
         ws_out = refs[k + 6:k + 6 + n_ws]
         ibuf, fbuf, isem, fsem = refs[k + 6 + n_ws:]
         deg_nd, row0_nd, nbr_hbm, h_hbm = streams[:4]
-
-        i = pl.program_id(0)
-        s0 = seed_s[i, 0]
-        s1 = seed_s[i, 1]
+        B = em_ref.shape[1]
+        lane_of_block = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
 
         def node_read_i32(nd, v):
-            return _read_elem(nd, ibuf, isem, jnp.int32(0), v)
+            return read_elem(nd, ibuf, isem, jnp.int32(0), v)
 
         def node_read_f32(nd, v):
-            return _read_elem(nd, fbuf, fsem, jnp.int32(0), v)
+            return read_elem(nd, fbuf, fsem, jnp.int32(0), v)
 
         def deg_of(v):
             # degrees_of() semantics: 0 for the -1 sentinel
@@ -194,29 +172,32 @@ def _make_kernel(program, params, *, kind: str, tile: int, max_tiles: int,
             def body(t, carry):
                 best_lk, best_nbr = carry
                 tstart = t * tile
-                nbr_raw = _read_span(nbr_hbm, ibuf, isem, r0row, tstart, tile)
-                h_raw = _read_span(h_hbm, fbuf, fsem, r0row, tstart, tile)
-                offs = tstart + _iota(tile)
-                mask = offs < deg
+                j, nbr_raw, h_raw = _span(r0row, tstart, tile,
+                                          (nbr_hbm, ibuf, isem),
+                                          (h_hbm, fbuf, fsem))
+                inwin = (j >= 0) & (j < tile)
+                mask = inwin & (tstart + j < deg)
                 nbr = jnp.where(mask, nbr_raw, -1)
                 h = jnp.where(mask, h_raw, jnp.float32(0.0))
+
+                def bc(x):
+                    return jnp.broadcast_to(x, j.shape)
+
                 ctx = EdgeCtx(
                     h=h, label=jnp.zeros_like(nbr), dist=jnp.ones_like(nbr),
-                    nbr=nbr,
-                    deg_cur=jnp.broadcast_to(deg, (tile,)),
-                    deg_prev=jnp.broadcast_to(dprev, (tile,)),
-                    cur=jnp.broadcast_to(cur, (tile,)),
-                    prev=jnp.broadcast_to(prev, (tile,)),
-                    step=jnp.broadcast_to(stepc, (tile,)))
-                w_raw = jax.vmap(program.edge_weight,
-                                 in_axes=(0, None, None))(ctx, params, ws_tree)
+                    nbr=nbr, deg_cur=bc(deg), deg_prev=bc(dprev),
+                    cur=bc(cur), prev=bc(prev), step=bc(stepc))
+                w_raw = tile_weight(ctx, params, ws_tree)
                 w = jnp.where(mask, jnp.maximum(w_raw, 0.0), 0.0)
-                u = _tile_uniforms_lane(sk0, sk1, t, tile)
+                u = tile_uniforms(sk0, sk1, t, j)
                 lk = jnp.where(mask, _log_keys(u, w), _NEG_INF)
-                b = jnp.argmax(lk)
-                upd = lk[b] > best_lk
-                return (jnp.where(upd, lk[b], best_lk),
-                        jnp.where(upd, nbr[b], best_nbr))
+                # first arg-max within the span (jnp.argmax's tie rule)
+                m = jnp.max(lk)
+                b = jnp.min(jnp.where((lk == m) & inwin, j, tile))
+                nb = lane_pick(nbr, b, where=jnp.where(inwin, j, -1))
+                upd = m > best_lk
+                return (jnp.where(upd, m, best_lk),
+                        jnp.where(upd, nb, best_nbr))
 
             _, best_nbr = jax.lax.fori_loop(
                 0, ntiles, body, (_NEG_INF, jnp.int32(-1)))
@@ -237,23 +218,19 @@ def _make_kernel(program, params, *, kind: str, tile: int, max_tiles: int,
 
             def body(c):
                 t, done, chosen = c
-                u_idx = _uniform_scalar_lane(sk0, sk1, 2 * t)
-                u_acc = _uniform_scalar_lane(sk0, sk1, 2 * t + 1)
+                u_idx = trial_uniform(sk0, sk1, 2 * t)
+                u_acc = trial_uniform(sk0, sk1, 2 * t + 1)
                 offset = jnp.minimum(
                     (u_idx * deg.astype(jnp.float32)).astype(jnp.int32),
                     jnp.maximum(deg - 1, 0))
-                valid = offset < deg
-                nbr_c = jnp.where(
-                    valid, _read_elem(nbr_hbm, ibuf, isem, r0row, offset), -1)
-                h_c = jnp.where(
-                    valid, _read_elem(h_hbm, fbuf, fsem, r0row, offset),
-                    jnp.float32(0.0))
+                nbr_c, h_c = read_elems(r0row, offset, (nbr_hbm, ibuf, isem),
+                                        (h_hbm, fbuf, fsem))
                 ctx = EdgeCtx(
                     h=h_c, label=jnp.zeros_like(nbr_c),
                     dist=jnp.ones_like(nbr_c), nbr=nbr_c, deg_cur=deg,
                     deg_prev=dprev, cur=cur, prev=prev, step=stepc)
-                flat = program.edge_weight(ctx, params, ws_tree)
-                w = jnp.where(valid, jnp.maximum(flat, 0.0), 0.0)
+                w = jnp.maximum(program.edge_weight(ctx, params, ws_tree),
+                                0.0)
                 accept = feasible & ~done & (u_acc * bound <= w) & (w > 0)
                 return (t + 1, done | accept,
                         jnp.where(accept, nbr_c, chosen))
@@ -289,7 +266,7 @@ def _make_kernel(program, params, *, kind: str, tile: int, max_tiles: int,
                 def sbody(c):
                     lo, hi = c
                     mid = (lo + hi) // 2
-                    go = _read_elem(cdf_hbm, fbuf, fsem, r0row, mid) <= target
+                    go = read_elem(cdf_hbm, fbuf, fsem, r0row, mid) <= target
                     return (jnp.where(go, mid + 1, lo),
                             jnp.where(go, hi, mid))
 
@@ -303,11 +280,11 @@ def _make_kernel(program, params, *, kind: str, tile: int, max_tiles: int,
                 col = jnp.minimum(
                     (u1 * deg.astype(jnp.float32)).astype(jnp.int32),
                     jnp.maximum(deg - 1, 0))
-                p_c = _read_elem(prob_hbm, fbuf, fsem, r0row, col)
-                a_c = _read_elem(alias_hbm, fbuf, fsem, r0row,
-                                 col).astype(jnp.int32)
+                p_c = read_elem(prob_hbm, fbuf, fsem, r0row, col)
+                a_c = read_elem(alias_hbm, fbuf, fsem, r0row,
+                                col).astype(jnp.int32)
                 sel = jnp.where(u2 < p_c, col, a_c)
-            nbr_c = _read_elem(nbr_hbm, ibuf, isem, r0row, sel)
+            nbr_c = read_elem(nbr_hbm, ibuf, isem, r0row, sel)
             nxt_pre = jnp.where(ok & (deg > 0) & (total > 0), nbr_c, -1)
             stale = act & ~ok
             dyn = reservoir_lane(cur, deg, sk0, sk1, prev, stepc, ws_tree,
@@ -318,69 +295,81 @@ def _make_kernel(program, params, *, kind: str, tile: int, max_tiles: int,
             return nxt, extra.astype(jnp.int32)
 
         # ------------------------------------------------- epoch step loop
-        def step_body(t, c):
-            cur, prev, stepc, alive, ws_leaves, emitted_v, flags_v = c
-            ws_tree = jax.tree_util.tree_unflatten(ws_treedef,
-                                                   list(ws_leaves))
-            deg = deg_of(cur)
-            wants = alive & (stepc < num_steps)
-            live = wants & (deg > 0)
-            # per-step key: stream_keys() folds the step counter
-            sk0, sk1 = threefry2x32(s0, s1, jnp.uint32(0), stepc)
-            if kind == "reservoir":
-                nxt = reservoir_lane(cur, deg, sk0, sk1, prev, stepc,
-                                     ws_tree, live)
-                extra = jnp.int32(0)
-            elif kind == "rejection":
-                nxt, extra = rejection_lane(cur, deg, sk0, sk1, prev, stepc,
-                                            ws_tree, live)
-            else:
-                nxt, extra = precomp_lane(cur, deg, sk0, sk1, prev, stepc,
-                                          ws_tree, live)
-            nxt = jnp.where(live, nxt, -1)
-            stepped = live & (nxt >= 0)
-            flagw = jnp.where(live, jnp.int32(1 << LIVE) | extra,
-                              jnp.int32(0))
-            # --- WalkProgram hooks, exactly as the staged step orders them
-            new_leaves = ws_leaves
-            stop = jnp.zeros_like(stepped)
-            if program.has_hooks:
-                tctx = EdgeCtx(
-                    h=jnp.float32(1.0), label=jnp.int32(-1),
-                    dist=jnp.int32(-1), nbr=nxt, deg_cur=deg,
-                    deg_prev=deg_of(prev), cur=cur, prev=prev, step=stepc)
-                new_ws = ws_tree
-                if program.on_step is not None:
-                    cand = program.on_step(tctx, params, ws_tree)
-                    new_leaves = tuple(
-                        jnp.where(stepped, n, o) for n, o in
-                        zip(jax.tree_util.tree_leaves(cand), ws_leaves))
-                    new_ws = jax.tree_util.tree_unflatten(ws_treedef,
-                                                          list(new_leaves))
-                if program.should_stop is not None:
-                    stop = stepped & program.should_stop(tctx, params, new_ws)
-            return (jnp.where(stepped, nxt, cur),
-                    jnp.where(stepped, cur, prev),
-                    stepc + stepped.astype(jnp.int32),
-                    alive & ~(wants & ~stepped) & ~stop,
-                    new_leaves,
-                    emitted_v.at[t].set(jnp.where(stepped, nxt, -1)),
-                    flags_v.at[t].set(flagw))
+        def lane_body(b, carry):
+            s0 = k0_s[b]
+            s1 = k1_s[b]
+            here = lane_of_block == b
 
-        init = (cur_s[i], prev_s[i], step_s[i], alive_s[i] != 0,
-                tuple(r[...][0] for r in ws_refs),
-                jnp.full((epoch_len,), -1, jnp.int32),
-                jnp.zeros((epoch_len,), jnp.int32))
-        cur, prev, stepc, alive, ws_leaves, emitted_v, flags_v = \
-            jax.lax.fori_loop(0, epoch_len, step_body, init)
-        em_ref[...] = emitted_v[None]
-        fl_ref[...] = flags_v[None]
-        ocur[0] = cur
-        oprev[0] = prev
-        ostep[0] = stepc
-        oalive[0] = alive.astype(jnp.int32)
-        for r, v in zip(ws_out, ws_leaves):
-            r[...] = v[None]
+            def put(ref, t, val):
+                # scalar → (t, b) of a (T, B) VMEM block: one row RMW
+                row = ref[pl.ds(t, 1), :]
+                ref[pl.ds(t, 1), :] = jnp.where(here, val, row)
+
+            def step_body(t, c):
+                cur, prev, stepc, alive, ws_leaves = c
+                ws_tree = jax.tree_util.tree_unflatten(ws_treedef,
+                                                       list(ws_leaves))
+                deg = deg_of(cur)
+                wants = alive & (stepc < num_steps)
+                live = wants & (deg > 0)
+                # per-step key: stream_keys() folds the step counter
+                sk0, sk1 = threefry2x32(s0, s1, jnp.uint32(0), stepc)
+                if kind == "reservoir":
+                    nxt = reservoir_lane(cur, deg, sk0, sk1, prev, stepc,
+                                         ws_tree, live)
+                    extra = jnp.int32(0)
+                elif kind == "rejection":
+                    nxt, extra = rejection_lane(cur, deg, sk0, sk1, prev,
+                                                stepc, ws_tree, live)
+                else:
+                    nxt, extra = precomp_lane(cur, deg, sk0, sk1, prev,
+                                              stepc, ws_tree, live)
+                nxt = jnp.where(live, nxt, -1)
+                stepped = live & (nxt >= 0)
+                flagw = jnp.where(live, jnp.int32(1 << LIVE) | extra,
+                                  jnp.int32(0))
+                # --- WalkProgram hooks, exactly as the staged step orders
+                new_leaves = ws_leaves
+                stop = jnp.zeros_like(stepped)
+                if program.has_hooks:
+                    tctx = EdgeCtx(
+                        h=jnp.float32(1.0), label=jnp.int32(-1),
+                        dist=jnp.int32(-1), nbr=nxt, deg_cur=deg,
+                        deg_prev=deg_of(prev), cur=cur, prev=prev,
+                        step=stepc)
+                    new_ws = ws_tree
+                    if program.on_step is not None:
+                        cand = program.on_step(tctx, params, ws_tree)
+                        new_leaves = tuple(
+                            jnp.where(stepped, n, o) for n, o in
+                            zip(jax.tree_util.tree_leaves(cand), ws_leaves))
+                        new_ws = jax.tree_util.tree_unflatten(
+                            ws_treedef, list(new_leaves))
+                    if program.should_stop is not None:
+                        stop = stepped & program.should_stop(tctx, params,
+                                                             new_ws)
+                put(em_ref, t, jnp.where(stepped, nxt, -1))
+                put(fl_ref, t, flagw)
+                return (jnp.where(stepped, nxt, cur),
+                        jnp.where(stepped, cur, prev),
+                        stepc + stepped.astype(jnp.int32),
+                        alive & ~(wants & ~stepped) & ~stop,
+                        new_leaves)
+
+            init = (cur_s[b], prev_s[b], step_s[b], alive_s[b] != 0,
+                    tuple(r[b] != 0 if dt == jnp.bool_ else r[b]
+                          for r, dt in zip(ws_refs, ws_dtypes)))
+            cur, prev, stepc, alive, ws_leaves = jax.lax.fori_loop(
+                0, epoch_len, step_body, init)
+            ocur[b] = cur
+            oprev[b] = prev
+            ostep[b] = stepc
+            oalive[b] = alive.astype(jnp.int32)
+            for r, v in zip(ws_out, ws_leaves):
+                r[b] = v.astype(r.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, B, lane_body, 0)
 
     return kernel
 
@@ -452,7 +441,7 @@ def make_streamed_epoch(program, params, *, kind: str, tile: int,
                 f"(fused_streams{' with bmax' if want == 5 else ''}), "
                 f"got {len(in_streams)}")
         W = int(state.cur.shape[0])
-        seeds = jnp.asarray(state.rng, jnp.uint32).reshape(W, -1)[:, :2]
+        seeds = threefry_seeds(state.rng)
         streams = list(in_streams)
         if kind in ("precomp_its", "precomp_alias"):
             if precomp is None or precomp.cdf2d is None:
@@ -468,32 +457,35 @@ def make_streamed_epoch(program, params, *, kind: str, tile: int,
             streams.append(pack_node_stream(
                 jnp.asarray(precomp.invalid, jnp.int32)))
         ws_leaves, ws_treedef = jax.tree_util.tree_flatten(state.wstate)
-        n_ws = len(ws_leaves)
+        why = wstate_refusal(ws_leaves, (W,))
+        if why is not None:  # fuse_report refuses such programs first
+            raise ValueError(why)
+        ws_dtypes = tuple(l.dtype for l in ws_leaves)
+        B, Wp = walker_grid(W)
+        lanes = [pad_lanes(x, Wp) for x in (
+            state.cur.astype(jnp.int32), state.prev.astype(jnp.int32),
+            state.step.astype(jnp.int32), state.alive.astype(jnp.int32),
+            seeds[:, 0], seeds[:, 1])]
+        ws_in = [pad_lanes(l.astype(jnp.int32) if l.dtype == bool else l, Wp)
+                 for l in ws_leaves]
         kernel = _make_kernel(
             program, params, kind=kind, tile=tile, max_tiles=int(max_tiles),
             rjs_trials=rjs_trials, rjs_max_rounds=rjs_max_rounds,
             epoch_len=int(epoch_len), num_steps=int(num_steps),
-            n_streams=len(streams), n_ws=n_ws, ws_treedef=ws_treedef)
-
-        def lane_block(leaf):
-            extra = leaf.ndim - 1
-            return pl.BlockSpec((1,) + leaf.shape[1:],
-                                lambda i, n=extra: (i,) + (0,) * n)
-
-        in_specs = ([pl.BlockSpec(memory_space=pltpu.SMEM)] * 5
-                    + [pl.BlockSpec(memory_space=pl.ANY)] * len(streams)
-                    + [lane_block(l) for l in ws_leaves])
-        out_specs = ([pl.BlockSpec((1, int(epoch_len)), lambda i: (i, 0))] * 2
-                     + [pl.BlockSpec((1,), lambda i: (i,))] * 4
-                     + [lane_block(l) for l in ws_leaves])
-        out_shape = ([jax.ShapeDtypeStruct((W, int(epoch_len)), jnp.int32)]
-                     * 2
-                     + [jax.ShapeDtypeStruct((W,), jnp.int32)] * 4
-                     + [jax.ShapeDtypeStruct(l.shape, l.dtype)
-                        for l in ws_leaves])
+            n_streams=len(streams), ws_dtypes=ws_dtypes,
+            ws_treedef=ws_treedef)
+        T = int(epoch_len)
+        step_rows = pl.BlockSpec((T, B), lambda i: (0, i))
         outs = pl.pallas_call(
-            kernel, grid=(W,), in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape,
+            kernel, grid=(Wp // B,),
+            in_specs=([smem_lanes(B)] * 6
+                      + [pl.BlockSpec(memory_space=pl.ANY)] * len(streams)
+                      + [smem_lanes(B)] * len(ws_in)),
+            out_specs=[step_rows] * 2 + [smem_lanes(B)] * (4 + len(ws_in)),
+            out_shape=([jax.ShapeDtypeStruct((T, Wp), jnp.int32)] * 2
+                       + [jax.ShapeDtypeStruct((Wp,), jnp.int32)] * 4
+                       + [jax.ShapeDtypeStruct((Wp,), l.dtype)
+                          for l in ws_in]),
             scratch_shapes=[
                 pltpu.VMEM((SUBLANES, LANES), jnp.int32),
                 pltpu.VMEM((SUBLANES, LANES), jnp.float32),
@@ -501,15 +493,16 @@ def make_streamed_epoch(program, params, *, kind: str, tile: int,
                 pltpu.SemaphoreType.DMA,
             ],
             interpret=interpret,
-        )(state.cur.astype(jnp.int32), state.prev.astype(jnp.int32),
-          state.step.astype(jnp.int32), state.alive.astype(jnp.int32),
-          seeds, *streams, *ws_leaves)
-        emitted, flags, cur, prev, stepc, alive = outs[:6]
+        )(*lanes, *streams, *ws_in)
+        emitted, flags = outs[0][:, :W], outs[1][:, :W]
+        cur, prev, stepc, alive = (o[:W] for o in outs[2:6])
+        ws_new = [o[:W] != 0 if dt == jnp.bool_ else o[:W]
+                  for o, dt in zip(outs[6:], ws_dtypes)]
         new_state = WalkerState(
             cur=cur, prev=prev, step=stepc, alive=alive.astype(bool),
             rng=state.rng, carry=state.carry,
-            wstate=jax.tree_util.tree_unflatten(ws_treedef, list(outs[6:])))
-        return new_state, emitted.T, StepStats.from_flag_bits(flags)
+            wstate=jax.tree_util.tree_unflatten(ws_treedef, ws_new))
+        return new_state, emitted, StepStats.from_flag_bits(flags.T)
 
     return epoch
 

@@ -4,14 +4,20 @@ TPU-native form of the ``core/precomp.py`` selectors (DESIGN.md §3.1 layout:
 tables live in the tile-aligned [R, 128] stream of ``ops.align_rows``, every
 node's row starting on a 128-lane boundary):
 
-* :func:`its_search` — one walker per grid step performs an O(log d)
-  binary search of its row's baked inclusive-prefix CDF.  Each probe DMAs
-  only the (8, 128) tile holding the probed element HBM→VMEM — ~log₂(d)
-  small copies instead of streaming the whole row, which is the entire
-  point of the precomputed regime (C-SAW).  Probes of a converged search
-  are never issued (while_loop, not a fixed-depth fori).
+* :func:`its_search` — each walker performs an O(log d) binary search of
+  its row's baked inclusive-prefix CDF.  Each probe DMAs only the (8, 128)
+  tile holding the probed element HBM→VMEM — ~log₂(d) small copies
+  instead of streaming the whole row, which is the entire point of the
+  precomputed regime (C-SAW).  Probes of a converged search are never
+  issued (while_loop, not a fixed-depth fori).
 * :func:`alias_pick` — O(1): two uniforms, one DMA into the prob stream and
   one into the alias stream, then accept-or-alias.
+
+Both run a block of walkers per grid step (:func:`walker_grid`): the
+per-walker scalars ride (B,) SMEM windows, so scalar memory does not grow
+with the pool, and a scalar is picked out of a VMEM tile with a dynamic
+sublane load plus a one-lane masked max (:func:`read_elem`) — the forms
+Mosaic (the Pallas TPU compiler) accepts.
 
 RNG is the same counter-based Threefry-2x32 the other kernels use
 (kernels/prng.py), with per-kernel salts so table draws never collide with
@@ -35,12 +41,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.prng import uniform_01, uniform_pair_01
+from repro.kernels.prng import (ALIAS_SALT, ITS_SALT, uniform_01,
+                                uniform_pair_01)
 from repro.kernels.ref import LANES, SUBLANES, TILE
 
-# fold-in salts (shared with the ref oracles; distinct from eRVS/eRJS)
-ITS_SALT = 0x175CDF
-ALIAS_SALT = 0xA11A5
+
+#: walkers per grid step for pools larger than one block.  A multiple of
+#: 1024 because XLA tiles rank-1 int32 arrays T(1024) on TPU, and a (B,)
+#: SMEM window must match that tiling (or span the whole array).
+BLOCK = 1024
 
 
 def default_interpret() -> bool:
@@ -50,45 +59,109 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _its_kernel(row0_ref, degs_ref, totals_ref, seeds_ref,  # SMEM scalars
-                cdf_hbm,  # ANY (HBM) [R, 128] tile-aligned CDF stream
-                off_ref,  # output (1,) block
-                buf, sem):  # scratch: VMEM (8, 128), DMA sem
-    i = pl.program_id(0)
-    r0 = row0_ref[i]
-    deg = degs_ref[i]
-    total = totals_ref[i]
-    k0 = seeds_ref[i, 0]
-    k1 = seeds_ref[i, 1]
-    u = uniform_01(k0, k1, jnp.uint32(0), jnp.uint32(ITS_SALT))
-    target = u * total
+def walker_grid(W: int):
+    """(block B, padded pool Wp): one block holding the whole pool when it
+    fits, else ``BLOCK``-walker blocks over a pool padded to a multiple."""
+    B = W if W <= BLOCK else BLOCK
+    return B, -(-W // B) * B
 
-    def probe(pos):
-        # DMA the (8, 128) tile holding cdf[row0·128 + pos]; align_rows
-        # pads the stream with ≥ 2 slack tiles, so the copy never runs
-        # off the end even for the last row.
-        t = pos // TILE
-        cp = pltpu.make_async_copy(
-            cdf_hbm.at[pl.ds(r0 + t * SUBLANES, SUBLANES), :], buf, sem)
+
+def pad_lanes(x, Wp: int):
+    """Zero-pad a per-walker array's leading dim to ``Wp`` lanes (pad
+    lanes read as empty: degree 0, not alive)."""
+    extra = Wp - x.shape[0]
+    if not extra:
+        return x
+    return jnp.pad(x, [(0, extra)] + [(0, 0)] * (x.ndim - 1))
+
+
+def smem_lanes(B: int):
+    """BlockSpec of one walker block's window of a rank-1 [Wp] array."""
+    return pl.BlockSpec((B,), lambda i: (i,), memory_space=pltpu.SMEM)
+
+
+def lane_pick(vec, idx, where=None):
+    """The element of ``vec`` at flat index ``idx`` as a scalar: a masked
+    max (exact for any value, -0.0 and NaN included) in place of the
+    dynamic slice Mosaic cannot lower.  ``where`` overrides the flat
+    index grid (e.g. a tile's offsets)."""
+    if where is None:
+        where = (jax.lax.broadcasted_iota(jnp.int32, vec.shape, 0)
+                 * vec.shape[-1]
+                 + jax.lax.broadcasted_iota(jnp.int32, vec.shape, 1))
+    low = (jnp.float32(-jnp.inf) if jnp.issubdtype(vec.dtype, jnp.floating)
+           else jnp.iinfo(vec.dtype).min)
+    return jnp.max(jnp.where(where == idx, vec, low))
+
+
+def dma_tiles(row, *streams):
+    """Copy the (8, 128) tile at sublane-row ``row`` of each ``(hbm, buf,
+    sem)`` stream into its VMEM buffer, all copies in flight together."""
+    copies = [pltpu.make_async_copy(hbm.at[pl.ds(row, SUBLANES), :], buf,
+                                    sem) for hbm, buf, sem in streams]
+    for cp in copies:
         cp.start()
+    for cp in copies:
         cp.wait()
-        return buf[...].reshape(TILE)[pos - t * TILE]
 
-    # first offset in [0, deg) whose inclusive prefix exceeds the target
-    def cond(c):
-        lo, hi = c
-        return lo < hi
 
-    def body(c):
-        lo, hi = c
-        mid = (lo + hi) // 2
-        go_right = probe(mid) <= target
-        return (jnp.where(go_right, mid + 1, lo),
-                jnp.where(go_right, hi, mid))
+def read_elems(r0, pos, *streams):
+    """Element ``pos`` (≥ 0) of the aligned row starting at sublane-row
+    ``r0`` of each ``(hbm, buf, sem)`` [R, 128] stream: one tile DMA per
+    stream, one sublane load, one lane pick."""
+    blk = pos // TILE
+    dma_tiles(r0 + blk * SUBLANES, *streams)
+    within = pos - blk * TILE
+    return tuple(lane_pick(buf[pl.ds(within // LANES, 1), :],
+                           within % LANES) for _, buf, _ in streams)
 
-    lo, _ = jax.lax.while_loop(cond, body, (jnp.int32(0), deg))
-    sel = jnp.clip(lo, 0, jnp.maximum(deg - 1, 0))
-    off_ref[0] = jnp.where((deg > 0) & (total > 0), sel, -1)
+
+def read_elem(hbm, buf, sem, r0, pos):
+    """:func:`read_elems` of one stream."""
+    return read_elems(r0, pos, (hbm, buf, sem))[0]
+
+
+def _its_kernel(row0_ref, degs_ref, totals_ref, k0_ref, k1_ref,  # SMEM (B,)
+                cdf_hbm,  # ANY (HBM) [R, 128] tile-aligned CDF stream
+                off_ref,  # output SMEM (B,)
+                buf, sem):  # scratch: VMEM (8, 128), DMA sem
+    def walker(b, carry):
+        r0 = row0_ref[b]
+        deg = degs_ref[b]
+        total = totals_ref[b]
+        u = uniform_01(k0_ref[b], k1_ref[b], jnp.uint32(0),
+                       jnp.uint32(ITS_SALT))
+        target = u * total
+
+        # first offset in [0, deg) whose inclusive prefix exceeds the
+        # target; align_rows pads the stream with ≥ 2 slack tiles, so a
+        # probe's tile DMA never runs off the end even for the last row
+        def cond(c):
+            lo, hi = c
+            return lo < hi
+
+        def body(c):
+            lo, hi = c
+            mid = (lo + hi) // 2
+            go_right = read_elem(cdf_hbm, buf, sem, r0, mid) <= target
+            return (jnp.where(go_right, mid + 1, lo),
+                    jnp.where(go_right, hi, mid))
+
+        lo, _ = jax.lax.while_loop(cond, body, (jnp.int32(0), deg))
+        sel = jnp.clip(lo, 0, jnp.maximum(deg - 1, 0))
+        off_ref[b] = jnp.where((deg > 0) & (total > 0), sel, -1)
+        return carry
+
+    jax.lax.fori_loop(0, off_ref.shape[0], walker, 0)
+
+
+def _per_walker(W, row0, degs, totals, seeds):
+    B, Wp = walker_grid(W)
+    seeds = jnp.asarray(seeds, jnp.uint32)
+    lanes = [pad_lanes(jnp.asarray(x, dt), Wp) for x, dt in
+             ((row0, jnp.int32), (degs, jnp.int32), (totals, jnp.float32),
+              (seeds[:, 0], jnp.uint32), (seeds[:, 1], jnp.uint32))]
+    return B, Wp, lanes
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -101,53 +174,42 @@ def its_search(cdf2d: jax.Array, row0: jax.Array, degs: jax.Array,
     Returns offset [W] int32 within each row (-1 for empty/zero rows).
     """
     W = row0.shape[0]
-    return pl.pallas_call(
+    B, Wp, lanes = _per_walker(W, row0, degs, totals, seeds)
+    out = pl.pallas_call(
         _its_kernel,
-        grid=(W,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # row0
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # degs
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # totals
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # seeds
-            pl.BlockSpec(memory_space=pl.ANY),  # CDF stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((W,), jnp.int32),
+        grid=(Wp // B,),
+        in_specs=[smem_lanes(B)] * 5
+        + [pl.BlockSpec(memory_space=pl.ANY)],  # CDF stays in HBM
+        out_specs=smem_lanes(B),
+        out_shape=jax.ShapeDtypeStruct((Wp,), jnp.int32),
         scratch_shapes=[
             pltpu.VMEM((SUBLANES, LANES), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(row0, degs, totals, seeds, cdf2d)
+    )(*lanes, cdf2d)
+    return out[:W]
 
 
-def _alias_kernel(row0_ref, degs_ref, totals_ref, seeds_ref,  # SMEM
+def _alias_kernel(row0_ref, degs_ref, totals_ref, k0_ref, k1_ref,  # SMEM
                   prob_hbm, alias_hbm,  # ANY (HBM) [R, 128] streams
-                  off_ref,  # output (1,) block
+                  off_ref,  # output SMEM (B,)
                   buf_p, buf_a, sem_p, sem_a):  # scratch
-    i = pl.program_id(0)
-    r0 = row0_ref[i]
-    deg = degs_ref[i]
-    total = totals_ref[i]
-    k0 = seeds_ref[i, 0]
-    k1 = seeds_ref[i, 1]
-    u1, u2 = uniform_pair_01(k0, k1, jnp.uint32(0), jnp.uint32(ALIAS_SALT))
-    col = jnp.minimum((u1 * deg.astype(jnp.float32)).astype(jnp.int32),
-                      jnp.maximum(deg - 1, 0))
-    t = col // TILE
-    cp_p = pltpu.make_async_copy(
-        prob_hbm.at[pl.ds(r0 + t * SUBLANES, SUBLANES), :], buf_p, sem_p)
-    cp_a = pltpu.make_async_copy(
-        alias_hbm.at[pl.ds(r0 + t * SUBLANES, SUBLANES), :], buf_a, sem_a)
-    cp_p.start()
-    cp_a.start()
-    cp_p.wait()
-    cp_a.wait()
-    within = col - t * TILE
-    p_col = buf_p[...].reshape(TILE)[within]
-    a_col = buf_a[...].reshape(TILE)[within].astype(jnp.int32)
-    sel = jnp.where(u2 < p_col, col, a_col)
-    off_ref[0] = jnp.where((deg > 0) & (total > 0), sel, -1)
+    def walker(b, carry):
+        r0 = row0_ref[b]
+        deg = degs_ref[b]
+        total = totals_ref[b]
+        u1, u2 = uniform_pair_01(k0_ref[b], k1_ref[b], jnp.uint32(0),
+                                 jnp.uint32(ALIAS_SALT))
+        col = jnp.minimum((u1 * deg.astype(jnp.float32)).astype(jnp.int32),
+                          jnp.maximum(deg - 1, 0))
+        p_col, a_col = read_elems(r0, col, (prob_hbm, buf_p, sem_p),
+                                  (alias_hbm, buf_a, sem_a))
+        sel = jnp.where(u2 < p_col, col, a_col.astype(jnp.int32))
+        off_ref[b] = jnp.where((deg > 0) & (total > 0), sel, -1)
+        return carry
+
+    jax.lax.fori_loop(0, off_ref.shape[0], walker, 0)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -162,19 +224,14 @@ def alias_pick(prob2d: jax.Array, alias2d: jax.Array, row0: jax.Array,
     Returns offset [W] int32 within each row (-1 for empty/zero rows).
     """
     W = row0.shape[0]
-    return pl.pallas_call(
+    B, Wp, lanes = _per_walker(W, row0, degs, totals, seeds)
+    out = pl.pallas_call(
         _alias_kernel,
-        grid=(W,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # row0
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # degs
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # totals
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # seeds
-            pl.BlockSpec(memory_space=pl.ANY),  # prob stream in HBM
-            pl.BlockSpec(memory_space=pl.ANY),  # alias stream in HBM
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((W,), jnp.int32),
+        grid=(Wp // B,),
+        in_specs=[smem_lanes(B)] * 5
+        + [pl.BlockSpec(memory_space=pl.ANY)] * 2,  # prob/alias in HBM
+        out_specs=smem_lanes(B),
+        out_shape=jax.ShapeDtypeStruct((Wp,), jnp.int32),
         scratch_shapes=[
             pltpu.VMEM((SUBLANES, LANES), jnp.float32),
             pltpu.VMEM((SUBLANES, LANES), jnp.float32),
@@ -182,4 +239,5 @@ def alias_pick(prob2d: jax.Array, alias2d: jax.Array, row0: jax.Array,
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(row0, degs, totals, seeds, prob2d, alias2d)
+    )(*lanes, prob2d, alias2d)
+    return out[:W]

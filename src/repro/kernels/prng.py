@@ -12,6 +12,7 @@ generator family JAX's host PRNG uses.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,6 +44,11 @@ def threefry2x32(k0, k1, x0, x1):
     return x0, x1
 
 
+def _top24(r):
+    # via int32: exact (values < 2^24), and Mosaic has no uint32->f32 cast
+    return (r >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+
+
 def uniform_01(k0, k1, c0, c1):
     """U(0,1) floats (never exactly 0) from two 32-bit counters.
 
@@ -50,8 +56,8 @@ def uniform_01(k0, k1, c0, c1):
     shift; safe for log().
     """
     r0, _ = threefry2x32(k0, k1, c0, c1)
-    f = (r0 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
-    return f + jnp.float32(0.5 / (1 << 24))
+    return _top24(r0) * jnp.float32(1.0 / (1 << 24)) \
+        + jnp.float32(0.5 / (1 << 24))
 
 
 def uniform_pair_01(k0, k1, c0, c1):
@@ -59,6 +65,36 @@ def uniform_pair_01(k0, k1, c0, c1):
     r0, r1 = threefry2x32(k0, k1, c0, c1)
     scale = jnp.float32(1.0 / (1 << 24))
     half = jnp.float32(0.5 / (1 << 24))
-    f0 = (r0 >> jnp.uint32(8)).astype(jnp.float32) * scale + half
-    f1 = (r1 >> jnp.uint32(8)).astype(jnp.float32) * scale + half
-    return f0, f1
+    return _top24(r0) * scale + half, _top24(r1) * scale + half
+
+
+# ------------------------------------------------------------------ streams
+# Every sampler draws from its walker's per-step key (``threefry_seeds`` of
+# ``WalkerState.stream_keys()``) at a counter pair of its own, so the
+# staged selectors and the Pallas kernels call the SAME functions below and
+# are bit-identical by construction:
+#   eRVS tile t, offset j     → (t, j)           (t < 2^31)
+#   eRJS trial counter c      → (ERJS_SALT, c)
+#   ITS / alias table draws   → (0, ITS_SALT) / (0, ALIAS_SALT)  (j < tile)
+ERJS_SALT = 0x8E75_0000
+ITS_SALT = 0x175CDF
+ALIAS_SALT = 0xA11A5
+
+
+def threefry_seeds(rng: jax.Array) -> jax.Array:
+    """[W] typed per-(walker, step) keys (or their raw [W, 2] data) →
+    [W, 2] uint32 Threefry key words."""
+    data = (jax.random.key_data(rng)
+            if jax.dtypes.issubdtype(rng.dtype, jax.dtypes.prng_key)
+            else rng)
+    return jnp.asarray(data, jnp.uint32).reshape(data.shape[0], -1)[:, :2]
+
+
+def tile_uniforms(k0, k1, t, j):
+    """eRVS key uniforms: offset ``j`` of neighbour tile ``t``."""
+    return uniform_01(k0, k1, t, j)
+
+
+def trial_uniform(k0, k1, c):
+    """eRJS proposal/acceptance uniform number ``c`` of a step."""
+    return uniform_01(k0, k1, jnp.uint32(ERJS_SALT), c)

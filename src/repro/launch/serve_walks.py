@@ -36,6 +36,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import EngineConfig
 from repro.core.runtime import STEP_EXEC_CHOICES
 from repro.core.samplers import PRECOMP_EXEC_CHOICES
@@ -290,6 +291,7 @@ def serve_tcp(svc: WalkService, args) -> None:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     if args.trace == "overload" and args.max_pending > args.queries // 4:
         # make the overload trace actually overload by default
         args.max_pending = max(args.queries // 4, 1)

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (EngineConfig, WalkEngine, available_samplers,
                         profile_edge_cost_ratio)
 from repro.core.cost_model import CostModel
@@ -110,6 +111,7 @@ def parse_workload_args(pairs) -> dict:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     if args.list_workloads:
         for name in sorted(WORKLOADS):
             print(name)

@@ -162,6 +162,20 @@ class TestShardedSchedulerArgs:
         np.testing.assert_array_equal(a.paths, b.paths)
         assert b.per_device is None
 
+    def test_replicated_views_placed_once_per_view(self):
+        """walk_batch/scheduler placement is cached per mesh: an unchanged
+        view is not broadcast again, a view swapped by a mutation is."""
+        eng = self._engine()
+        first = eng.replicated_views(walker_mesh(1))
+        again = eng.replicated_views(walker_mesh(1))
+        assert all(a is b for a, b in zip(first, again))
+        eng.apply_updates(inserts=([0], [1], np.float32([2.5])))
+        tables, graph, stats = eng.replicated_views(walker_mesh(1))
+        assert graph is not first[1] and stats is not first[2]
+        v = jnp.arange(60, dtype=jnp.int32)
+        np.testing.assert_array_equal(np.asarray(graph.row_degs(v)),
+                                      np.asarray(eng.graph.row_degs(v)))
+
     def test_walker_spec_single_device_mesh(self):
         mesh = walker_mesh(1)
         from jax.sharding import PartitionSpec as P

@@ -263,8 +263,9 @@ class TestAdmissionQueueProperties:
         now = 0.0
         for op in ops:
             if op[0] == "push":
+                room = len(q) < 3
                 ok = q.push(Item(priority=op[1], submit_time=now))
-                assert ok == (len(q) <= 3)
+                assert ok == room and len(q) <= 3
             elif op[0] == "pop":
                 q.pop_batch(op[1], now=now)
             elif op[0] == "tick":
