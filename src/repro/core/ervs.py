@@ -15,7 +15,11 @@ Two statistically equivalent implementations:
 
 Both scan the neighbour list in [W, tile] blocks with a fori_loop, so memory
 traffic is one streaming pass over each walker's row — the paper's "roughly
-halves the costly memory accesses" claim vs prefix-sum RVS.
+halves the costly memory accesses" claim vs prefix-sum RVS.  Every lane runs
+every trip, so a pass costs ``W × tile`` lane-slots per trip however few of
+them hold a neighbour; :func:`tile_pass` gives a pass's trip count (the loop
+bound both functions use) and the neighbour entries it reads, which the
+samplers report as the ``ervs_trips`` / ``ervs_edges`` step counters.
 
 Engine integration: registered as the ``ervs`` / ``ervs_jump`` samplers
 (``samplers.ERVSSampler`` / ``ERVSJumpSampler``); both honour the runtime
@@ -36,6 +40,24 @@ from repro.graphs.csr import CSRGraph
 from repro.kernels.prng import threefry_seeds, tile_uniforms
 
 NEG_INF = jnp.float32(-jnp.inf)
+
+
+def tile_pass(graph: CSRGraph, cur: jax.Array, active: jax.Array, tile: int,
+              max_tiles: Optional[int] = None) -> Tuple[jax.Array, jax.Array]:
+    """(trips, edges) of one tile-loop pass over the ``active`` lanes.
+
+    ``trips`` is the loop bound: tiles needed by the longest active row,
+    capped at ``max_tiles``.  ``edges`` is the neighbour entries the pass
+    reads, ``Σ min(degree, trips·tile)`` over the active lanes, so
+    ``edges <= trips · tile · W`` always.  Both are int32 scalars, and a
+    max and a sum over the lanes — exact under a sharded lane axis.
+    """
+    deg_act = jnp.where(active, degrees_of_cached(graph, cur), 0)
+    trips = (jnp.max(deg_act) + tile - 1) // tile
+    if max_tiles is not None:
+        trips = jnp.minimum(trips, max_tiles)
+    edges = jnp.sum(jnp.minimum(deg_act, trips * tile), dtype=jnp.int32)
+    return trips.astype(jnp.int32), edges
 
 
 def _log_keys(u: jax.Array, w: jax.Array) -> jax.Array:
@@ -72,10 +94,7 @@ def ervs_step(
     # dynamic trip count: tiles needed by the *active* partition only — when
     # the cost model sends every high-degree walker to eRJS, the eRVS pass
     # shrinks accordingly (fori_loop with a traced bound lowers to while).
-    deg_act = jnp.where(active, degrees_of_cached(graph, cur), 0)
-    needed = (jnp.max(deg_act) + tile - 1) // tile
-    if max_tiles is not None:
-        needed = jnp.minimum(needed, max_tiles)
+    needed, _ = tile_pass(graph, cur, active, tile, max_tiles)
 
     def body(t, carry):
         best_lk, best_nbr = carry
@@ -110,8 +129,8 @@ def ervs_jump_step(
     max_tiles: Optional[int] = None,
     active: Optional[jax.Array] = None,
     wstate=None,
-) -> Tuple[jax.Array, jax.Array]:
-    """A-ExpJ (jump) variant.  Returns (next_nodes [W], rng_draws [W]).
+) -> jax.Array:
+    """A-ExpJ (jump) variant.  Returns next nodes [W] (or -1; -2 inactive).
 
     Each *lane* l ∈ [0, tile) owns the strided neighbour subsequence
     {l, l+tile, l+2·tile, …} of its walker, runs sequential A-ExpJ on it
@@ -119,21 +138,19 @@ def ervs_jump_step(
     reduction arg-maxes over lanes — exactly the paper's per-thread local
     max + cross-thread reduction (Fig. 4b), with threads → vector lanes.
 
-    rng_draws counts actual draws (consumed only at threshold crossings);
-    on SIMD hardware the arithmetic cost of a masked lane is not saved, but
-    the Pallas kernel skips whole *blocks* — this function is the semantic
-    oracle and the statistics source (Fig. 12a JUMP ablation).
+    On SIMD hardware the arithmetic cost of a masked lane is not saved, so
+    this function is the semantic oracle of the jump technique.  The
+    block-jump kernel (kernels/ervs_kernel.py) skips whole *blocks*; it and
+    its reference ``kernels/ref.py:ervs_select_ref`` count the draws they
+    consume, the Fig. 12a JUMP ablation's statistics source.
     """
     W = cur.shape[0]
     if active is None:
         active = jnp.ones((W,), bool)
-    deg_act = jnp.where(active, degrees_of_cached(graph, cur), 0)
-    needed = (jnp.max(deg_act) + tile - 1) // tile
-    if max_tiles is not None:
-        needed = jnp.minimum(needed, max_tiles)
+    needed, _ = tile_pass(graph, cur, active, tile, max_tiles)
 
     def body(t, carry):
-        lk_max, nbr_best, thresh, cumw, draws = carry
+        lk_max, nbr_best, thresh, cumw = carry
         ctx, mask = tile_ctx(graph, workload, cur, prev, step,
                              jnp.full((W,), t * tile, jnp.int32), tile)
         w = eval_weights(workload, params, ctx, mask, wstate)  # [W, tile]
@@ -157,23 +174,19 @@ def ervs_jump_step(
         thresh = jnp.where(take, new_thresh_val, thresh)
         cumw = jnp.where(take, 0.0, cumw + jnp.where(mask, w, 0.0))
         nbr_best = jnp.where(take, ctx.nbr, nbr_best)
-        # dtype pinned: under JAX_ENABLE_X64 an unpinned int32 sum promotes
-        # to int64 and breaks the fori_loop carry contract
-        draws = draws + jnp.sum(take, axis=1, dtype=jnp.int32) * 2
-        return (lk_new, nbr_best, thresh, cumw, draws)
+        return (lk_new, nbr_best, thresh, cumw)
 
     init = (
         jnp.full((W, tile), NEG_INF),
         jnp.full((W, tile), -1, jnp.int32),
         jnp.zeros((W, tile), jnp.float32),  # thresh: first item always "crosses" via is_first
         jnp.zeros((W, tile), jnp.float32),
-        jnp.zeros((W,), jnp.int32),
     )
-    lk, nbr, _, _, draws = jax.lax.fori_loop(0, needed, body, init)
+    lk, nbr, _, _ = jax.lax.fori_loop(0, needed, body, init)
     lane = jnp.argmax(lk, axis=1)
     best = jnp.take_along_axis(nbr, lane[:, None], axis=1)[:, 0]
     best = jnp.where(jnp.max(lk, axis=1) > NEG_INF, best, -1)
-    return jnp.where(active, best, -2), draws
+    return jnp.where(active, best, -2)
 
 
 def _tile_uniforms(rng: jax.Array, t, shape) -> jax.Array:

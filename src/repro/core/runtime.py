@@ -41,6 +41,7 @@ batch-invariance contract, extended).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from collections import deque
 from typing import Optional, Tuple
@@ -281,8 +282,8 @@ class EpochScheduler:
         #: query id each slot serves (-1 = free)
         self.slot_query = np.full(self.W, -1, np.int64)
         #: accumulated StepStats.host_totals over every epoch run so far
-        self.totals = {"live": 0, "rjs_served": 0, "fallbacks": 0,
-                       "precomp_served": 0, "stale_served": 0}
+        self.totals = dict.fromkeys(
+            (f.name for f in dataclasses.fields(StepStats)), 0)
         self.rebuilt_rows = 0
         self.epoch_idx = 0
         self.dev_queries = np.zeros(self.n_dev, np.int64)
@@ -338,8 +339,10 @@ class EpochScheduler:
         grown[:self.paths.shape[0]] = self.paths
         self.paths = grown
 
+    @functools.partial(jax.profiler.annotate_function, name="sched.admit")
     def admit(self, query_ids, starts) -> int:
-        """Install queries into free slots (epoch-boundary refill).
+        """Install queries into free slots (epoch-boundary refill), inside
+        the profiler span ``sched.admit``.
 
         ``query_ids`` pick the RNG streams and path rows; the caller must
         not exceed ``free_slots()``.  Returns how many were admitted.
@@ -448,10 +451,46 @@ class EpochScheduler:
                                                 self.mesh)
 
     # -------------------------------------------------------------- epochs
+    @functools.partial(jax.profiler.annotate_function,
+                       name="sched.run_epoch")
     def run_epoch(self) -> EpochReport:
         """Compact / drain on the engine-absolute cadences, execute one
         jitted epoch (``T`` scan steps) against the pinned table view,
-        harvest emitted path entries, and report completions."""
+        harvest emitted path entries, and report completions.
+
+        Runs inside the profiler span ``sched.run_epoch``, whose children
+        split its host time in order: ``sched.maintain`` (compaction,
+        re-pin, rebuild drain), ``sched.dispatch`` (the step pull and the
+        epoch program's enqueue), ``sched.wait`` (blocked on the device
+        for the emitted nodes) and ``sched.harvest`` (path scatter,
+        telemetry, completions)."""
+        eng = self.engine
+        with jax.profiler.TraceAnnotation("sched.maintain"):
+            self._maintain()
+        # Serve against the PINNED graph/stats/table views (re-pinned
+        # above on any mutation-clock bump) — run_epoch_fn resolves
+        # fused-vs-staged per epoch, so a mutation mid-serve flips the
+        # path the moment the engine's streams change.  Sharded runs keep
+        # the staged scan: the mega-step kernel is one Pallas program
+        # over the whole lane pool, and mixing it with a GSPMD-
+        # partitioned epoch would change nothing but plumbing — both
+        # paths are bit-identical, so this is purely an exec choice.
+        with jax.profiler.TraceAnnotation("sched.dispatch"):
+            step0 = np.asarray(self.state.step)
+            self.state, emitted, stats = eng.run_epoch_fn(
+                self.state, self.tables, self.graph_view, self.stats_view,
+                epoch_len=self.T, num_steps=self.num_steps,
+                pad=self.pad_view, max_tiles=self.max_tiles_view,
+                fused=(self.mesh is None))
+        with jax.profiler.TraceAnnotation("sched.wait"):
+            emitted = np.asarray(emitted)  # [T, W]
+        with jax.profiler.TraceAnnotation("sched.harvest"):
+            return self._harvest(step0, emitted, stats)
+
+    def _maintain(self) -> None:
+        """The epoch boundary's upkeep before dispatch: scheduled
+        compaction, re-pin after a mutation, the rebuild drain, and the
+        epoch clocks."""
         eng = self.engine
         cfg = eng.config
         # scheduled overlay compaction (config.compact_interval), keyed —
@@ -495,21 +534,11 @@ class EpochScheduler:
             self.adopt_tables()
         self.epoch_idx += 1
         eng.epoch_clock += 1
-        # Serve against the PINNED graph/stats/table views (re-pinned
-        # above on any mutation-clock bump) — run_epoch_fn resolves
-        # fused-vs-staged per epoch, so a mutation mid-serve flips the
-        # path the moment the engine's streams change.  Sharded runs keep
-        # the staged scan: the mega-step kernel is one Pallas program
-        # over the whole lane pool, and mixing it with a GSPMD-
-        # partitioned epoch would change nothing but plumbing — both
-        # paths are bit-identical, so this is purely an exec choice.
-        step0 = np.asarray(self.state.step)
-        self.state, emitted, stats = eng.run_epoch_fn(
-            self.state, self.tables, self.graph_view, self.stats_view,
-            epoch_len=self.T, num_steps=self.num_steps,
-            pad=self.pad_view, max_tiles=self.max_tiles_view,
-            fused=(self.mesh is None))
-        emitted = np.asarray(emitted)  # [T, W]
+
+    def _harvest(self, step0: np.ndarray, emitted: np.ndarray,
+                 stats: StepStats) -> EpochReport:
+        """Scatter the epoch's emitted nodes into :attr:`paths`, add its
+        telemetry to :attr:`totals`, and free the finished walkers' slots."""
         step1 = np.asarray(self.state.step)
         alive1 = np.asarray(self.state.alive)
         occupied = np.nonzero(self.slot_query >= 0)[0]
@@ -704,14 +733,16 @@ class WalkEngine:
             rjs_trials=cfg.rjs_trials, rjs_max_rounds=cfg.rjs_max_rounds)
         engine = self
 
-        def epoch(state, precomp, streams, epoch_len: int, num_steps: int,
-                  max_tiles: int):
+        # XLA module jit_epoch_fused, distinct from jit_epoch_staged
+        def epoch_fused(state, precomp, streams, epoch_len: int,
+                        num_steps: int, max_tiles: int):
             engine.fused_traces += 1  # trace-time only (see __init__)
             return inner(state, precomp, streams, epoch_len, num_steps,
                          max_tiles)
 
         return jax.jit(
-            epoch, static_argnames=("epoch_len", "num_steps", "max_tiles"))
+            epoch_fused,
+            static_argnames=("epoch_len", "num_steps", "max_tiles"))
 
     def _refresh_fused_streams(self) -> None:
         """(Re)build the host-side aligned edge streams the fused
@@ -829,12 +860,16 @@ class WalkEngine:
                               rjs_served=sel.rjs_served,
                               fallbacks=sel.fallbacks,
                               precomp_served=sel.precomp_served,
-                              stale_served=sel.stale_served)
+                              stale_served=sel.stale_served,
+                              ervs_trips=sel.ervs_trips,
+                              ervs_edges=sel.ervs_edges)
             return new_state, jnp.where(stepped, nxt, -1), stats
 
-        def epoch(state: WalkerState, precomp, graph, stats,
-                  epoch_len: int, num_steps: int, pad: int,
-                  max_tiles: int):
+        # the function's name is the XLA module's (jit_epoch_staged): the
+        # name a profiler trace finds the staged epoch program by
+        def epoch_staged(state: WalkerState, precomp, graph, stats,
+                         epoch_len: int, num_steps: int, pad: int,
+                         max_tiles: int):
             engine.staged_traces += 1  # trace-time only (see __init__)
             ctx = dataclasses.replace(base_ctx, precomp=precomp,
                                       graph=graph, stats=stats, pad=pad,
@@ -848,7 +883,7 @@ class WalkEngine:
                 body, state, None, length=epoch_len)
             return state, emitted, step_stats
 
-        return epoch
+        return epoch_staged
 
     def run_epoch_fn(self, state, tables, graph, stats, *, epoch_len: int,
                      num_steps: int, pad: int, max_tiles: int,

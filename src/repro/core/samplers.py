@@ -56,7 +56,7 @@ from repro.core.baselines import BASELINE_STEP_FNS
 from repro.core.ctxutil import degrees_of, eval_weights, tile_ctx
 from repro.core.erjs import erjs_step
 from repro.core.ervs import (NEG_INF, _log_keys, _tile_uniforms,
-                             ervs_jump_step, ervs_step)
+                             ervs_jump_step, ervs_step, tile_pass)
 from repro.core.types import EdgeCtx, WalkerState
 from repro.graphs.csr import dist_code
 
@@ -97,6 +97,13 @@ class Selection:
     # active lanes that hit a stale (invalidated) table row and took the
     # dynamic path while the row awaits its background rebuild
     stale_served: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
+    # eRVS tile-loop trips and neighbour entries read, summed over the
+    # ervs_step / ervs_jump_step passes the sampler ran (StepStats has
+    # the same fields)
+    ervs_trips: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
+    ervs_edges: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
     # sampler-owned cross-step state; the engine stores it in
     # WalkerState.carry for the next step (None = carry nothing)
@@ -237,8 +244,11 @@ class ERVSSampler(Sampler):
                         state.cur, state.prev, state.step, rng,
                         tile=ctx.config.tile, max_tiles=ctx.max_tiles,
                         active=active, wstate=state.wstate)
+        trips, edges = tile_pass(ctx.graph, state.cur, active,
+                                 ctx.config.tile, ctx.max_tiles)
         zero = jnp.int32(0)
-        return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero)
+        return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
+                         ervs_trips=trips, ervs_edges=edges)
 
     def fused_kind(self, *, usable, has_precomp):
         return "reservoir"
@@ -251,12 +261,15 @@ class ERVSJumpSampler(Sampler):
     caps = SamplerCaps(supports_partition=True)
 
     def select(self, ctx, state, rng, *, active):
-        nxt, _ = ervs_jump_step(ctx.graph, ctx.workload, ctx.params,
-                                state.cur, state.prev, state.step, rng,
-                                tile=ctx.config.tile, max_tiles=ctx.max_tiles,
-                                active=active, wstate=state.wstate)
+        nxt = ervs_jump_step(ctx.graph, ctx.workload, ctx.params,
+                             state.cur, state.prev, state.step, rng,
+                             tile=ctx.config.tile, max_tiles=ctx.max_tiles,
+                             active=active, wstate=state.wstate)
+        trips, edges = tile_pass(ctx.graph, state.cur, active,
+                                 ctx.config.tile, ctx.max_tiles)
         zero = jnp.int32(0)
-        return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero)
+        return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
+                         ervs_trips=trips, ervs_edges=edges)
 
 
 # ---------------------------------------------------------- rejection side
@@ -360,42 +373,56 @@ class PartitionedSampler(Sampler):
 
     def _reservoir_select(self, ctx, state, rng, deg, active):
         """Reservoir partition, optionally split by degree (hubs take the
-        jump variant — the ROADMAP's per-node reservoir choice)."""
+        jump variant — the ROADMAP's per-node reservoir choice).  Returns
+        (next nodes, tile-loop trips, edges read) summed over the passes."""
         if self.reservoir_hi is None:
-            return self.reservoir.select(ctx, state, rng, active=active).next_nodes
+            with jax.named_scope("ervs"):
+                r = self.reservoir.select(ctx, state, rng, active=active)
+            return r.next_nodes, r.ervs_trips, r.ervs_edges
         hi = active & (deg >= ctx.config.jump_threshold)
         lo = active & ~hi
-        r_lo = self.reservoir.select(ctx, state, rng, active=lo)
-        r_hi = self.reservoir_hi.select(ctx, state, rng, active=hi)
-        return jnp.where(hi, r_hi.next_nodes, r_lo.next_nodes)
+        with jax.named_scope("ervs"):
+            r_lo = self.reservoir.select(ctx, state, rng, active=lo)
+        with jax.named_scope("ervs_hub"):
+            r_hi = self.reservoir_hi.select(ctx, state, rng, active=hi)
+        return (jnp.where(hi, r_hi.next_nodes, r_lo.next_nodes),
+                r_lo.ervs_trips + r_hi.ervs_trips,
+                r_lo.ervs_edges + r_hi.ervs_edges)
 
     def select(self, ctx, state, rng, *, active):
+        # each regime runs under a named scope (precomp, cost_model, erjs,
+        # ervs, ervs_hub), so a trace maps its loops to the regime
         deg = degrees_of(ctx.graph, state.cur)
-        est = ctx.estimates(state)
+        with jax.named_scope("cost_model"):
+            est = ctx.estimates(state)
         # --- third regime: static rows served from the baked tables ------
         if self.precomp_regime and ctx.precomp is not None:
-            # routing discounts by the transient stale fraction: as the
-            # rebuild queue backs up, fewer lanes are sent to bounce off
-            # invalid rows (see CostModel.prefer_precomp)
-            prefer = ctx.config.cost_model.prefer_precomp(
-                deg, frac_stale=ctx.precomp.frac_stale())
-            valid = ctx.precomp.row_valid(state.cur)
-            want_pre = active & valid & prefer
-            stale_pre = active & ~valid & prefer
-            nxt_pre = precomp_table_select(ctx, state, rng, want_pre,
-                                           kind="its")
+            with jax.named_scope("precomp"):
+                # routing discounts by the transient stale fraction: as
+                # the rebuild queue backs up, fewer lanes are sent to
+                # bounce off invalid rows (see CostModel.prefer_precomp)
+                prefer = ctx.config.cost_model.prefer_precomp(
+                    deg, frac_stale=ctx.precomp.frac_stale())
+                valid = ctx.precomp.row_valid(state.cur)
+                want_pre = active & valid & prefer
+                stale_pre = active & ~valid & prefer
+                nxt_pre = precomp_table_select(ctx, state, rng, want_pre,
+                                               kind="its")
         else:
             want_pre = jnp.zeros_like(active)
             stale_pre = jnp.zeros_like(active)
             nxt_pre = jnp.full_like(state.cur, -1)
         rest = active & ~want_pre
         # --- Eq. 11 split on the remaining lanes -------------------------
-        want_rjs = self.policy(ctx, state, est, deg, rest, rng) & rest
-        nxt_rjs, fb = self.rejection.propose(ctx, state, rng,
-                                             est.bound_max, want_rjs)
+        with jax.named_scope("cost_model"):
+            want_rjs = self.policy(ctx, state, est, deg, rest, rng) & rest
+        with jax.named_scope("erjs"):
+            nxt_rjs, fb = self.rejection.propose(ctx, state, rng,
+                                                 est.bound_max, want_rjs)
         # reservoir partition = lanes the policy kept + rejection fallbacks
         res_active = rest & ((~want_rjs) | fb)
-        nxt_res = self._reservoir_select(ctx, state, rng, deg, res_active)
+        nxt_res, trips, edges = self._reservoir_select(ctx, state, rng, deg,
+                                                       res_active)
         nxt = jnp.where(res_active, nxt_res,
                         jnp.where(want_rjs, nxt_rjs, -1))
         nxt = jnp.where(want_pre, nxt_pre, nxt)
@@ -416,6 +443,7 @@ class PartitionedSampler(Sampler):
                 (want_pre & (nxt_pre >= 0)).astype(jnp.int32)),
             stale_served=jnp.sum(
                 (stale_pre & (nxt >= 0)).astype(jnp.int32)),
+            ervs_trips=trips, ervs_edges=edges,
         )
 
     def fused_kind(self, *, usable, has_precomp):
@@ -562,7 +590,8 @@ class _PrecompBase(Sampler):
         if ctx.precomp is None:  # workload not static-provable
             dyn = self._fallback.select(ctx, state, rng, active=active)
             return Selection(next_nodes=dyn.next_nodes, rjs_served=zero,
-                             fallbacks=zero)
+                             fallbacks=zero, ervs_trips=dyn.ervs_trips,
+                             ervs_edges=dyn.ervs_edges)
         ok = active & ctx.precomp.row_valid(state.cur)
         nxt_pre = precomp_table_select(ctx, state, rng, ok, kind=self.kind)
         stale = active & ~ok
@@ -575,7 +604,8 @@ class _PrecompBase(Sampler):
             next_nodes=nxt, rjs_served=zero, fallbacks=zero,
             precomp_served=jnp.sum((ok & (nxt_pre >= 0)).astype(jnp.int32)),
             stale_served=jnp.sum(
-                (stale & (dyn.next_nodes >= 0)).astype(jnp.int32)))
+                (stale & (dyn.next_nodes >= 0)).astype(jnp.int32)),
+            ervs_trips=dyn.ervs_trips, ervs_edges=dyn.ervs_edges)
 
     def fused_kind(self, *, usable, has_precomp):
         # With baked tables the kernel serves the table regime (stale rows

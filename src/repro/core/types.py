@@ -332,6 +332,16 @@ class StepStats:
     # queue drains; 0 once every stale row has been re-baked
     stale_served: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
+    # staged-path work counters, not lane counts: eRVS tile-loop trips
+    # this step, summed over the reservoir passes, and the neighbour
+    # entries those trips read (core/ervs.py:tile_pass).  Their share
+    # ervs_edges / (ervs_trips · tile · slots) is the tile loops' useful
+    # fraction of lane-slots.  The fused mega-step runs its own per-lane
+    # loops and reports 0 for both.
+    ervs_trips: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
+    ervs_edges: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
 
     def host_totals(self) -> dict:
         """Each counter summed to a host int, keyed by field name.
@@ -349,11 +359,15 @@ class StepStats:
         """Reduce a [W, T] int32 flag-bit matrix to per-step counters
         ([T]-leaf StepStats, the same pytree the staged epoch scan
         stacks).  Integer sums per bit, so the reduction is order-free
-        exact — fused and staged telemetry match bit for bit."""
+        exact — fused and staged lane counters match bit for bit; the
+        staged-only tile-loop counters read 0."""
         def count(bit):
             return jnp.sum((flags >> bit) & 1, axis=0, dtype=jnp.int32)
 
-        return cls(live=count(cls.LIVE), rjs_served=count(cls.RJS),
+        live = count(cls.LIVE)
+        zero = jnp.zeros_like(live)
+        return cls(live=live, rjs_served=count(cls.RJS),
                    fallbacks=count(cls.FALLBACK),
                    precomp_served=count(cls.PRECOMP),
-                   stale_served=count(cls.STALE))
+                   stale_served=count(cls.STALE),
+                   ervs_trips=zero, ervs_edges=zero)

@@ -62,7 +62,7 @@ import numpy as np
 
 from repro.core import EngineConfig, WalkEngine
 from repro.core.runtime import DEFAULT_EPOCH_LEN
-from repro.core.types import WalkProgram
+from repro.core.types import StepStats, WalkProgram
 from repro.graphs import GraphDelta
 from repro.serving.stats import LatencyWindow
 
@@ -831,8 +831,8 @@ class WalkService:
     def stats(self) -> ServiceStats:
         """Counter snapshot; ``stats().conserves()`` holds at any point
         between ``submit``/``step`` calls."""
-        totals = {"live": 0, "rjs_served": 0, "fallbacks": 0,
-                  "precomp_served": 0, "stale_served": 0}
+        totals = dict.fromkeys(
+            (f.name for f in dataclasses.fields(StepStats)), 0)
         rebuilt = 0
         per_tenant = {}
         for t in self._tenants.values():

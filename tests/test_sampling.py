@@ -58,8 +58,8 @@ class TestDistributions:
 
     def test_ervs_jump(self, setup):
         g, wl, params, p, nbr, cur, prev, step, rng = setup
-        out, _ = ervs_jump_step(g, wl, params, cur, prev, step, rng,
-                                tile=32, max_tiles=4)
+        out = ervs_jump_step(g, wl, params, cur, prev, step, rng,
+                             tile=32, max_tiles=4)
         assert tvd(np.asarray(out), p, nbr) < TVD_MAX
 
     def test_erjs_with_compiler_bound(self, setup):
