@@ -19,17 +19,23 @@ halves the costly memory accesses" claim vs prefix-sum RVS.  Every lane runs
 every trip, so a pass costs ``W × tile`` lane-slots per trip however few of
 them hold a neighbour; :func:`tile_pass` gives a pass's trip count (the loop
 bound both functions use) and the neighbour entries it reads, which the
-samplers report as the ``ervs_trips`` / ``ervs_edges`` step counters.
+samplers report as the ``ervs_trips`` / ``ervs_edges`` step counters, and
+``trips × W`` as ``ervs_lane_trips``.
+
+:func:`compact_lanes` runs a pass over a sparse partition's active lanes
+only, ``LANE_CHUNK`` of them at a time: a lane's choice reads nothing but
+its own row, keys and state, so the compacted pass picks exactly what the
+dense one would.
 
 Engine integration: registered as the ``ervs`` / ``ervs_jump`` samplers
 (``samplers.ERVSSampler`` / ``ERVSJumpSampler``); both honour the runtime
 partition mask, so either can serve as the reservoir half of a
-``PartitionedSampler``.
+``PartitionedSampler``, which runs them through :func:`compact_lanes`.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +46,12 @@ from repro.graphs.csr import CSRGraph
 from repro.kernels.prng import threefry_seeds, tile_uniforms
 
 NEG_INF = jnp.float32(-jnp.inf)
+
+#: lanes per shard in one chunk of :func:`compact_lanes`: two 128-lane
+#: vregs, above the reservoir lanes a node2vec step leaves after the cost
+#: model's split on a power-law graph, so one chunk is the normal case.
+#: Read when the enclosing program is traced.
+LANE_CHUNK = 256
 
 
 def tile_pass(graph: CSRGraph, cur: jax.Array, active: jax.Array, tile: int,
@@ -58,6 +70,64 @@ def tile_pass(graph: CSRGraph, cur: jax.Array, active: jax.Array, tile: int,
         trips = jnp.minimum(trips, max_tiles)
     edges = jnp.sum(jnp.minimum(deg_act, trips * tile), dtype=jnp.int32)
     return trips.astype(jnp.int32), edges
+
+
+def compact_lanes(pass_fn: Callable, lanes, active: jax.Array,
+                  shards: int = 1) -> Tuple[jax.Array, jax.Array]:
+    """Run a reservoir pass over the ``active`` lanes only.
+
+    ``pass_fn(lanes, active) -> (next [n], trips)`` is the pass over ``n``
+    lanes, ``lanes`` a pytree of per-lane arrays (leading dim W).  The
+    slot axis is viewed as ``[shards, W // shards]`` and each shard's
+    active lanes are taken in ascending slot order, ``K = min(LANE_CHUNK,
+    W // shards)`` per shard at a time: a chunk runs ``pass_fn`` on its
+    ``shards · K`` gathered lanes and its picks are scattered back to
+    their slots.  Every gather and scatter stays inside its shard, so a
+    pool sharded over ``shards`` devices compacts with no traffic but the
+    scalar max behind the chunk count, ``ceil(largest shard's active
+    count / K)``, a ``while`` bound.  Where one chunk would hold a whole
+    shard, the dense pass runs as it is.
+
+    Returns (next [W], -2 on inactive lanes; lane-trips, each chunk's
+    trips times the lanes of its tile, summed).
+    """
+    W = active.shape[0]
+    spd = W // shards
+    K = min(LANE_CHUNK, spd)
+    if K == spd:
+        nxt, trips = pass_fn(lanes, active)
+        return jnp.where(active, nxt, -2), trips * W
+    act = active.reshape(shards, spd)
+    count = jnp.sum(act, axis=1, dtype=jnp.int32)
+    # each shard's active slots first, in ascending order, padded to whole
+    # chunks (a padding column is never valid)
+    order = jnp.argsort(~act, axis=1, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, ((0, 0), (0, -(-spd // K) * K - spd)))
+    n_chunks = (jnp.max(count) + K - 1) // K
+    by_shard = jax.tree_util.tree_map(
+        lambda x: x.reshape((shards, spd) + x.shape[1:]), lanes)
+
+    def gather(x, idx):
+        return jax.vmap(lambda xs, i: xs[i])(x, idx).reshape(
+            (shards * K,) + x.shape[2:])
+
+    def body(c, carry):
+        out, lane_trips = carry
+        idx = jax.lax.dynamic_slice_in_dim(order, c * K, K, axis=1)
+        valid = (c * K + jnp.arange(K, dtype=jnp.int32))[None, :] \
+            < count[:, None]
+        nxt, trips = pass_fn(
+            jax.tree_util.tree_map(lambda x: gather(x, idx), by_shard),
+            valid.reshape(-1))
+        dest = jnp.where(valid, idx, spd)  # out of range: dropped
+        out = jax.vmap(lambda o, i, v: o.at[i].set(v, mode="drop"))(
+            out, dest, nxt.reshape(shards, K))
+        return out, lane_trips + trips * (shards * K)
+
+    out, lane_trips = jax.lax.fori_loop(
+        0, n_chunks, body,
+        (jnp.full((shards, spd), -2, jnp.int32), jnp.int32(0)))
+    return out.reshape(W), lane_trips
 
 
 def _log_keys(u: jax.Array, w: jax.Array) -> jax.Array:

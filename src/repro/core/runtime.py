@@ -481,7 +481,7 @@ class EpochScheduler:
                 self.state, self.tables, self.graph_view, self.stats_view,
                 epoch_len=self.T, num_steps=self.num_steps,
                 pad=self.pad_view, max_tiles=self.max_tiles_view,
-                fused=(self.mesh is None))
+                fused=(self.mesh is None), shards=self.n_dev)
         with jax.profiler.TraceAnnotation("sched.wait"):
             emitted = np.asarray(emitted)  # [T, W]
         with jax.profiler.TraceAnnotation("sched.harvest"):
@@ -659,7 +659,8 @@ class WalkEngine:
         # pow2 patch capacity + the sticky pow2 pad bucket those shapes.
         self._epoch_fn = jax.jit(
             self._make_epoch(),
-            static_argnames=("epoch_len", "num_steps", "pad", "max_tiles"))
+            static_argnames=("epoch_len", "num_steps", "pad", "max_tiles",
+                             "shards"))
         self._fused_epoch_fn = (self._build_fused_epoch()
                                 if self._fused_kind else None)
         self._fused_streams = None
@@ -862,18 +863,19 @@ class WalkEngine:
                               precomp_served=sel.precomp_served,
                               stale_served=sel.stale_served,
                               ervs_trips=sel.ervs_trips,
-                              ervs_edges=sel.ervs_edges)
+                              ervs_edges=sel.ervs_edges,
+                              ervs_lane_trips=sel.ervs_lane_trips)
             return new_state, jnp.where(stepped, nxt, -1), stats
 
         # the function's name is the XLA module's (jit_epoch_staged): the
         # name a profiler trace finds the staged epoch program by
         def epoch_staged(state: WalkerState, precomp, graph, stats,
                          epoch_len: int, num_steps: int, pad: int,
-                         max_tiles: int):
+                         max_tiles: int, shards: int):
             engine.staged_traces += 1  # trace-time only (see __init__)
             ctx = dataclasses.replace(base_ctx, precomp=precomp,
                                       graph=graph, stats=stats, pad=pad,
-                                      max_tiles=max_tiles)
+                                      max_tiles=max_tiles, shards=shards)
 
             def body(carry, _):
                 new_state, emitted, stats_t = step(carry, ctx, num_steps)
@@ -887,14 +889,15 @@ class WalkEngine:
 
     def run_epoch_fn(self, state, tables, graph, stats, *, epoch_len: int,
                      num_steps: int, pad: int, max_tiles: int,
-                     fused: bool = True):
+                     fused: bool = True, shards: int = 1):
         """Execute one jitted epoch against explicit graph/stats/table
         views — the single entry point both drivers (EpochScheduler and
         walk_batch) call, so the fused-vs-staged pick lives in one place.
         Runs the fused mega-step when the engine has one AND its edge
         streams exist for the current graph (see _refresh_fused_streams);
-        ``fused=False`` forces the staged scan (sharded epochs).  Both
-        paths are bit-identical."""
+        ``fused=False`` forces the staged scan (sharded epochs), and
+        ``shards`` is the number of devices its slot axis is block-sharded
+        over.  Both paths are bit-identical."""
         if (fused and self._fused_epoch_fn is not None
                 and self._fused_streams is not None):
             return self._fused_epoch_fn(
@@ -902,7 +905,7 @@ class WalkEngine:
                 num_steps=num_steps, max_tiles=max_tiles)
         return self._epoch_fn(state, tables, graph, stats,
                               epoch_len=epoch_len, num_steps=num_steps,
-                              pad=pad, max_tiles=max_tiles)
+                              pad=pad, max_tiles=max_tiles, shards=shards)
 
     def replicated_views(self, mesh):
         """(tables, graph, stats) with one copy on every device of
@@ -1146,7 +1149,7 @@ class WalkEngine:
             state, tables, graph, stats,
             epoch_len=num_steps, num_steps=num_steps, pad=self.pad,
             max_tiles=self.max_tiles,
-            fused=(devices is None or devices <= 1))
+            fused=(devices is None or devices <= 1), shards=devices or 1)
         return emitted.T, stats
 
     # -------------------------------------------------------- graph updates

@@ -56,7 +56,8 @@ from repro.core.baselines import BASELINE_STEP_FNS
 from repro.core.ctxutil import degrees_of, eval_weights, tile_ctx
 from repro.core.erjs import erjs_step
 from repro.core.ervs import (NEG_INF, _log_keys, _tile_uniforms,
-                             ervs_jump_step, ervs_step, tile_pass)
+                             compact_lanes, ervs_jump_step, ervs_step,
+                             tile_pass)
 from repro.core.types import EdgeCtx, WalkerState
 from repro.graphs.csr import dist_code
 
@@ -98,12 +99,15 @@ class Selection:
     # dynamic path while the row awaits its background rebuild
     stale_served: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
-    # eRVS tile-loop trips and neighbour entries read, summed over the
+    # eRVS tile-loop trips, neighbour entries read and lane-trips (trips
+    # times the lanes of the tile that ran them), summed over the
     # ervs_step / ervs_jump_step passes the sampler ran (StepStats has
     # the same fields)
     ervs_trips: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
     ervs_edges: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
+    ervs_lane_trips: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
     # sampler-owned cross-step state; the engine stores it in
     # WalkerState.carry for the next step (None = carry nothing)
@@ -130,6 +134,9 @@ class SamplerContext:
     # the workload is is_static-provable AND the sampler asked for them
     # (caps.needs_precomp); None otherwise.
     precomp: Optional[precomp_mod.PrecompTables] = None
+    # devices the slot axis is block-sharded over: the reservoir passes
+    # compact their lanes within each device's block (compact_lanes)
+    shards: int = 1
 
     def bound_inputs(self, state: WalkerState) -> fc.BoundInputs:
         vs = jnp.maximum(state.cur, 0)
@@ -233,6 +240,31 @@ def available_samplers() -> Tuple[str, ...]:
 
 
 # ------------------------------------------------------------- reservoirs
+def _dense_pass(ctx, state, active, nxt) -> Selection:
+    """Selection of a ``[W, tile]`` tile-loop pass over every lane."""
+    trips, edges = tile_pass(ctx.graph, state.cur, active, ctx.config.tile,
+                             ctx.max_tiles)
+    zero = jnp.int32(0)
+    return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
+                     ervs_trips=trips, ervs_edges=edges,
+                     ervs_lane_trips=trips * state.cur.shape[0])
+
+
+def _compacted(sampler, ctx, state, rng, active):
+    """An eRVS-family ``sampler.select`` over the ``active`` lanes only,
+    compacted per shard (``core/ervs.py`` ``compact_lanes``).  Returns
+    (next nodes, trips, edges, lane-trips): trips and edges those of the
+    dense pass over the partition, which the chunks read between them."""
+    def run(lanes, on):
+        sel = sampler.select(ctx, *lanes, active=on)
+        return sel.next_nodes, sel.ervs_trips
+
+    nxt, lane_trips = compact_lanes(run, (state, rng), active, ctx.shards)
+    trips, edges = tile_pass(ctx.graph, state.cur, active, ctx.config.tile,
+                             ctx.max_tiles)
+    return nxt, trips, edges, lane_trips
+
+
 class ERVSSampler(Sampler):
     """eRVS — streaming exponential-key reservoir (paper §3.2, Alg. 1)."""
 
@@ -244,11 +276,7 @@ class ERVSSampler(Sampler):
                         state.cur, state.prev, state.step, rng,
                         tile=ctx.config.tile, max_tiles=ctx.max_tiles,
                         active=active, wstate=state.wstate)
-        trips, edges = tile_pass(ctx.graph, state.cur, active,
-                                 ctx.config.tile, ctx.max_tiles)
-        zero = jnp.int32(0)
-        return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
-                         ervs_trips=trips, ervs_edges=edges)
+        return _dense_pass(ctx, state, active, nxt)
 
     def fused_kind(self, *, usable, has_precomp):
         return "reservoir"
@@ -265,11 +293,7 @@ class ERVSJumpSampler(Sampler):
                              state.cur, state.prev, state.step, rng,
                              tile=ctx.config.tile, max_tiles=ctx.max_tiles,
                              active=active, wstate=state.wstate)
-        trips, edges = tile_pass(ctx.graph, state.cur, active,
-                                 ctx.config.tile, ctx.max_tiles)
-        zero = jnp.int32(0)
-        return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
-                         ervs_trips=trips, ervs_edges=edges)
+        return _dense_pass(ctx, state, active, nxt)
 
 
 # ---------------------------------------------------------- rejection side
@@ -373,21 +397,21 @@ class PartitionedSampler(Sampler):
 
     def _reservoir_select(self, ctx, state, rng, deg, active):
         """Reservoir partition, optionally split by degree (hubs take the
-        jump variant — the ROADMAP's per-node reservoir choice).  Returns
-        (next nodes, tile-loop trips, edges read) summed over the passes."""
+        jump variant — the ROADMAP's per-node reservoir choice), each pass
+        run on its own lanes only (:func:`_compacted`).  Returns (next
+        nodes, tile-loop trips, edges read, lane-trips) summed over the
+        passes."""
         if self.reservoir_hi is None:
             with jax.named_scope("ervs"):
-                r = self.reservoir.select(ctx, state, rng, active=active)
-            return r.next_nodes, r.ervs_trips, r.ervs_edges
+                return _compacted(self.reservoir, ctx, state, rng, active)
         hi = active & (deg >= ctx.config.jump_threshold)
         lo = active & ~hi
         with jax.named_scope("ervs"):
-            r_lo = self.reservoir.select(ctx, state, rng, active=lo)
+            r_lo = _compacted(self.reservoir, ctx, state, rng, lo)
         with jax.named_scope("ervs_hub"):
-            r_hi = self.reservoir_hi.select(ctx, state, rng, active=hi)
-        return (jnp.where(hi, r_hi.next_nodes, r_lo.next_nodes),
-                r_lo.ervs_trips + r_hi.ervs_trips,
-                r_lo.ervs_edges + r_hi.ervs_edges)
+            r_hi = _compacted(self.reservoir_hi, ctx, state, rng, hi)
+        return (jnp.where(hi, r_hi[0], r_lo[0]),
+                *(a + b for a, b in zip(r_lo[1:], r_hi[1:])))
 
     def select(self, ctx, state, rng, *, active):
         # each regime runs under a named scope (precomp, cost_model, erjs,
@@ -421,8 +445,8 @@ class PartitionedSampler(Sampler):
                                                  est.bound_max, want_rjs)
         # reservoir partition = lanes the policy kept + rejection fallbacks
         res_active = rest & ((~want_rjs) | fb)
-        nxt_res, trips, edges = self._reservoir_select(ctx, state, rng, deg,
-                                                       res_active)
+        nxt_res, trips, edges, lane_trips = self._reservoir_select(
+            ctx, state, rng, deg, res_active)
         nxt = jnp.where(res_active, nxt_res,
                         jnp.where(want_rjs, nxt_rjs, -1))
         nxt = jnp.where(want_pre, nxt_pre, nxt)
@@ -443,7 +467,7 @@ class PartitionedSampler(Sampler):
                 (want_pre & (nxt_pre >= 0)).astype(jnp.int32)),
             stale_served=jnp.sum(
                 (stale_pre & (nxt >= 0)).astype(jnp.int32)),
-            ervs_trips=trips, ervs_edges=edges,
+            ervs_trips=trips, ervs_edges=edges, ervs_lane_trips=lane_trips,
         )
 
     def fused_kind(self, *, usable, has_precomp):
@@ -591,7 +615,8 @@ class _PrecompBase(Sampler):
             dyn = self._fallback.select(ctx, state, rng, active=active)
             return Selection(next_nodes=dyn.next_nodes, rjs_served=zero,
                              fallbacks=zero, ervs_trips=dyn.ervs_trips,
-                             ervs_edges=dyn.ervs_edges)
+                             ervs_edges=dyn.ervs_edges,
+                             ervs_lane_trips=dyn.ervs_lane_trips)
         ok = active & ctx.precomp.row_valid(state.cur)
         nxt_pre = precomp_table_select(ctx, state, rng, ok, kind=self.kind)
         stale = active & ~ok
@@ -605,7 +630,8 @@ class _PrecompBase(Sampler):
             precomp_served=jnp.sum((ok & (nxt_pre >= 0)).astype(jnp.int32)),
             stale_served=jnp.sum(
                 (stale & (dyn.next_nodes >= 0)).astype(jnp.int32)),
-            ervs_trips=dyn.ervs_trips, ervs_edges=dyn.ervs_edges)
+            ervs_trips=dyn.ervs_trips, ervs_edges=dyn.ervs_edges,
+            ervs_lane_trips=dyn.ervs_lane_trips)
 
     def fused_kind(self, *, usable, has_precomp):
         # With baked tables the kernel serves the table regime (stale rows

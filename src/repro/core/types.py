@@ -333,14 +333,19 @@ class StepStats:
     stale_served: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
     # staged-path work counters, not lane counts: eRVS tile-loop trips
-    # this step, summed over the reservoir passes, and the neighbour
-    # entries those trips read (core/ervs.py:tile_pass).  Their share
-    # ervs_edges / (ervs_trips · tile · slots) is the tile loops' useful
-    # fraction of lane-slots.  The fused mega-step runs its own per-lane
-    # loops and reports 0 for both.
+    # this step, summed over the reservoir passes, the neighbour entries
+    # those trips read (core/ervs.py:tile_pass), and the lane-trips run:
+    # each loop's trips times the lanes of its tile (all slots for a
+    # dense pass, shards · K for a compacted chunk, core/ervs.py:
+    # compact_lanes).  ervs_lane_trips / (ervs_trips · slots) is the share
+    # of the dense passes' lane-trips still run, ervs_edges /
+    # (ervs_lane_trips · tile) the loops' useful fraction of lane-slots.
+    # The fused mega-step runs its own per-lane loops and reports 0.
     ervs_trips: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
     ervs_edges: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
+    ervs_lane_trips: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
 
     def host_totals(self) -> dict:
@@ -370,4 +375,4 @@ class StepStats:
                    fallbacks=count(cls.FALLBACK),
                    precomp_served=count(cls.PRECOMP),
                    stale_served=count(cls.STALE),
-                   ervs_trips=zero, ervs_edges=zero)
+                   ervs_trips=zero, ervs_edges=zero, ervs_lane_trips=zero)

@@ -63,6 +63,41 @@ for method in ["ervs", "adaptive", "interleaved"]:
                                   err_msg=method)
     assert int(np.asarray(s1.live).sum()) == int(np.asarray(s2.live).sum())
 
+# adaptive node2vec with its reservoir passes compacted, in chunks of 2 of
+# each device's 8 slots (one rejection trial a step leaves the reservoir
+# side many fallbacks): still bit-identical to one device, and the
+# compiled sharded epoch moves no lane between the devices
+import re
+from repro.core import ervs
+ervs.LANE_CHUNK = 2
+eng = WalkEngine(g, node2vec(), EngineConfig(method="adaptive", tile=64,
+                                             rjs_trials=1, rjs_max_rounds=1))
+runs = {}
+for devices in (1, 2):
+    sched = eng.scheduler(num_steps=9, key=key, slots=16, epoch_len=3,
+                          capacity=40, devices=devices)
+    pending = list(range(40))
+    while pending or sched.busy:
+        n = sched.free_slots().size
+        take, pending = pending[:n], pending[n:]
+        if take:
+            sched.admit(take, np.asarray(take, np.int32) % 200)
+        sched.run_epoch()
+    runs[devices] = sched
+one, two = runs[1], runs[2]
+np.testing.assert_array_equal(one.paths, two.paths)
+for k in one.totals:
+    if k != "ervs_lane_trips":
+        assert one.totals[k] == two.totals[k], (k, one.totals, two.totals)
+assert 0 < two.totals["ervs_lane_trips"] < two.totals["ervs_trips"] * 16
+hlo = eng._epoch_fn.lower(
+    two.state, two.tables, two.graph_view, two.stats_view, epoch_len=3,
+    num_steps=9, pad=two.pad_view, max_tiles=two.max_tiles_view,
+    shards=2).compile().as_text()
+moved = re.findall(r"\s(all-gather|all-to-all|collective-permute)\S*\(", hlo)
+assert not moved, moved
+assert re.search(r"\sall-reduce\S*\(", hlo)  # the scalar sums and maxes
+
 # spec machinery on a real 2-device mesh: slot dims shard, indivisible
 # pools fall back to replication instead of mis-sharding
 mesh = walker_mesh(2)

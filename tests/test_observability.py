@@ -1,14 +1,18 @@
 """The staged step's eRVS work counters (``StepStats.ervs_trips`` /
-``ervs_edges``, docs/architecture.md "Observability").
+``ervs_edges`` / ``ervs_lane_trips``, docs/architecture.md
+"Observability").
 
 On a small graph with one planted hub, a staged ``adaptive`` engine's
 per-epoch counters must equal a numpy recount: per scan step, the
 reservoir lanes the cost model's routing leaves (Eq. 11), split at
 ``jump_threshold`` into the plain and the hub (A-ExpJ) pass, each pass
 running ``ceil(longest active row / tile)`` trips and reading every
-active lane's row.  The totals must not depend on the device count, and
-the fused mega-step, which has no such loop, reports 0 for both while
-its paths stay bit-identical to the staged scan's."""
+active lane's row.  Lane-trips recount the compacted passes: per shard,
+chunks of ``LANE_CHUNK`` active lanes in ascending slot order, each
+chunk's trips from its own longest row.  Trips and edges must not depend
+on the device count, and the fused mega-step, which has no such loop,
+reports 0 for all three while its paths stay bit-identical to the staged
+scan's."""
 import os
 import subprocess
 import sys
@@ -19,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineConfig, WalkEngine
+from repro.core import ervs
 from repro.core.types import WalkerState
 from repro.graphs import random_graph
 from repro.graphs.csr import from_edges
@@ -84,16 +89,34 @@ def pass_work(deg: np.ndarray, max_tiles: int):
     return trips, int(np.minimum(deg, trips * TILE).sum())
 
 
-def recount(eng, paths, slot_q, step0):
-    """Numpy recount of one epoch's (trips, edges, steps with a hub pass)
-    from the lanes' positions and the cost model's routing."""
+def lane_trips(deg: np.ndarray, slots: np.ndarray, max_tiles: int,
+               shards: int):
+    """Lane-trips of one compacted pass over lanes of degree ``deg`` in
+    the ascending ``slots``: per shard, chunks of K active lanes, each
+    chunk running its own longest row's trips on ``shards · K`` lanes (or
+    the dense pass, trips on every slot, where K is a whole shard)."""
+    spd = SLOTS // shards
+    k = min(ervs.LANE_CHUNK, spd)
+    if k == spd:
+        return pass_work(deg, max_tiles)[0] * SLOTS
+    per = [deg[slots // spd == d] for d in range(shards)]
+    return sum(
+        pass_work(np.concatenate([p[c:c + k] for p in per]), max_tiles)[0]
+        * shards * k
+        for c in range(0, max(p.size for p in per), k))
+
+
+def recount(eng, paths, slot_q, step0, shards=1):
+    """Numpy recount of one epoch's (trips, edges, steps with a hub pass,
+    lane-trips) from the lanes' positions and the cost model's routing."""
     deg_all = np.diff(np.asarray(eng.graph.indptr))
     occ = np.nonzero(slot_q >= 0)[0]
-    trips = edges = hub_steps = 0
+    trips = edges = hub_steps = lanes = 0
     for j in range(EPOCH):
         # every row is non-empty, so no walk dead-ends: a lane is live
         # while its walk has steps left
         q, s = slot_q[occ], step0[occ] + j
+        slots = occ[s < STEPS]
         q, s = q[s < STEPS], s[s < STEPS]
         if q.size == 0:
             continue
@@ -115,7 +138,9 @@ def recount(eng, paths, slot_q, step0):
         for mask in (lo, hi):
             t, e = pass_work(deg[mask], eng.max_tiles)
             trips, edges = trips + t, edges + e
-    return trips, edges, hub_steps
+            lanes += lane_trips(deg[mask], slots[mask], eng.max_tiles,
+                                shards)
+    return trips, edges, hub_steps, lanes
 
 
 def test_counters_match_a_numpy_recount():
@@ -128,8 +153,10 @@ def test_counters_match_a_numpy_recount():
         # with no rejection fallbacks, the reservoir side is exactly the
         # lanes the policy kept
         assert stats["fallbacks"] == 0
-        trips, edges, h = recount(eng, sched.paths, slot_q, step0)
+        trips, edges, h, lanes = recount(eng, sched.paths, slot_q, step0)
         assert (stats["ervs_trips"], stats["ervs_edges"]) == (trips, edges)
+        # a pool of SLOTS <= LANE_CHUNK runs the dense passes
+        assert stats["ervs_lane_trips"] == lanes == trips * SLOTS
         # invariants: a trip reads at most tile entries of every slot, and
         # a step runs at most one full hub pass plus one plain pass
         assert stats["ervs_edges"] <= stats["ervs_trips"] * TILE * SLOTS
@@ -141,6 +168,27 @@ def test_counters_match_a_numpy_recount():
     assert 0 < sched.totals["rjs_served"] < sched.totals["live"]
     assert hub_steps > 0
     assert sched.totals["ervs_trips"] > hub_steps
+
+
+def test_lane_trips_match_a_numpy_recount(monkeypatch):
+    """With chunks of 4 lanes the passes compact: lane-trips fall below
+    the dense ``trips × slots`` and equal their recount, while paths and
+    every other counter stay those of the dense passes."""
+    graph = hub_graph()
+    starts = np.arange(40, dtype=np.int32) % NODES
+    dense, _ = drive(hub_engine(graph), starts)
+    monkeypatch.setattr(ervs, "LANE_CHUNK", 4)
+    eng = hub_engine(graph)
+    sched, epochs = drive(eng, starts)
+    for slot_q, step0, stats in epochs:
+        *_, lanes = recount(eng, sched.paths, slot_q, step0)
+        assert stats["ervs_lane_trips"] == lanes
+    np.testing.assert_array_equal(dense.paths, sched.paths)
+    lane_trips = sched.totals.pop("ervs_lane_trips")
+    assert 0 < lane_trips < sched.totals["ervs_trips"] * SLOTS
+    assert dense.totals.pop("ervs_lane_trips") \
+        == dense.totals["ervs_trips"] * SLOTS
+    assert dense.totals == sched.totals
 
 
 _CHILD = r"""
@@ -160,6 +208,21 @@ four, _ = t.drive(eng, starts, devices=4)
 np.testing.assert_array_equal(one.paths, four.paths)
 assert one.totals == four.totals, (one.totals, four.totals)
 assert one.totals["ervs_trips"] > 0
+# chunks of 2 lanes: each device's 4 slots compact on their own, and the
+# lane-trips follow the chunking of each device count
+t.ervs.LANE_CHUNK = 2
+for devices, shards in ((None, 1), (4, 4)):
+    eng = t.hub_engine(t.hub_graph())
+    sched, epochs = t.drive(eng, starts, devices=devices)
+    for slot_q, step0, stats in epochs:
+        *_, lanes = t.recount(eng, sched.paths, slot_q, step0, shards)
+        assert stats["ervs_lane_trips"] == lanes, (shards, stats, lanes)
+    np.testing.assert_array_equal(one.paths, sched.paths)
+    lane_trips = sched.totals.pop("ervs_lane_trips")
+    assert 0 < lane_trips < sched.totals["ervs_trips"] * t.SLOTS
+    rest = dict(one.totals)
+    del rest["ervs_lane_trips"]
+    assert rest == sched.totals, (shards, rest, sched.totals)
 print("COUNTERS-X4-OK", one.totals)
 """
 
@@ -194,5 +257,7 @@ def test_fused_mega_step_reports_zero_and_matches_staged_paths():
     np.testing.assert_array_equal(st.paths, fu.paths)
     assert st.totals["ervs_trips"] > 0 and st.totals["ervs_edges"] > 0
     assert fu.totals["ervs_trips"] == fu.totals["ervs_edges"] == 0
+    assert st.totals["ervs_lane_trips"] == st.totals["ervs_trips"] * 8
+    assert fu.totals["ervs_lane_trips"] == 0
     lanes = {k: v for k, v in st.totals.items() if not k.startswith("ervs")}
     assert lanes == {k: fu.totals[k] for k in lanes}

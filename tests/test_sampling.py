@@ -14,9 +14,10 @@ from repro.core import (CostModel, EngineConfig, METHODS, Sampler,
 from repro.core.baselines import (als_step, its_step, rjs_maxreduce_step,
                                   rvs_prefix_step)
 from repro.core.erjs import erjs_step
+from repro.core import ervs as ervs_mod
 from repro.core.ervs import ervs_jump_step, ervs_step
 from repro.core.ctxutil import degrees_of
-from repro.graphs import node_stats, random_graph
+from repro.graphs import node_stats, power_law_graph, random_graph
 from repro.walks import deepwalk, node2vec, second_order_pagerank
 
 N = 3000
@@ -308,3 +309,102 @@ class TestStreamingScheduler:
         res = eng.run(starts, num_steps=6, key=key)
         np.testing.assert_array_equal(np.asarray(paths_b), res.paths[:, 1:])
         assert int(np.asarray(stats.live).sum()) == res.live_steps
+
+
+# ------------------------------------------------ reservoir lane compaction
+class TestLaneCompaction:
+    """``PartitionedSampler`` runs each reservoir pass on its partition's
+    lanes only, ``LANE_CHUNK`` at a time (``core/ervs.py``
+    ``compact_lanes``).  A lane's pick reads only its own row, keys and
+    state, so picks, whole paths and every other counter must be those of
+    the dense pass, which runs when a chunk would hold the whole pool."""
+
+    W, K, HUB = 32, 8, 24
+    DENSE = 1 << 30  # LANE_CHUNK of at least W: the dense pass
+
+    def engine(self, method):
+        # one rejection trial in one round leaves many lanes to the §7.1
+        # fallback, so "erjs" hands its reservoir side a real partition
+        g = power_law_graph(300, 8, seed=4)
+        return WalkEngine(g, node2vec(), EngineConfig(
+            method=method, tile=16, jump_threshold=self.HUB, rjs_trials=1,
+            rjs_max_rounds=1, step_exec="staged"))
+
+    def lanes(self, eng):
+        """A [W] walker state on nodes of every degree, hubs among them."""
+        deg = np.asarray(eng.graph.degrees())
+        rng = np.random.default_rng(0)
+        nodes = np.argsort(-deg)[:200]
+        cur = rng.choice(nodes, self.W, replace=False)
+        cur[:4] = nodes[:4]  # the largest rows: the hub pass
+        indptr, indices = (np.asarray(a) for a in (eng.graph.indptr,
+                                                   eng.graph.indices))
+        prev = np.asarray([indices[indptr[v]] for v in cur])
+        prev[::5] = -1  # first steps: no previous node
+        return WalkerState(
+            cur=jnp.asarray(cur, jnp.int32),
+            prev=jnp.asarray(prev, jnp.int32),
+            step=jnp.asarray(rng.integers(0, 9, self.W), jnp.int32),
+            alive=jnp.ones(self.W, bool),
+            rng=jax.random.key_data(
+                jax.random.split(jax.random.key(5), self.W)),
+            carry=None,
+            wstate=eng.workload.init_wstate_batch(
+                jnp.arange(self.W, dtype=jnp.int32)))
+
+    def passes(self, eng, state, masks):
+        """_reservoir_select's (next, trips, edges, lane-trips) per mask."""
+        ctx = eng.sampler_ctx
+        deg = degrees_of(eng.graph, state.cur)
+        f = jax.jit(lambda on: eng.sampler._reservoir_select(
+            ctx, state, state.stream_keys(), deg, on))
+        return [tuple(np.asarray(x) for x in f(jnp.asarray(m)))
+                for m in masks]
+
+    def drive(self, eng, n_queries=70):
+        sched = eng.scheduler(num_steps=9, key=jax.random.key(3),
+                              slots=self.W, epoch_len=4, capacity=n_queries)
+        pending = list(range(n_queries))
+        while pending or sched.busy:
+            n = sched.free_slots().size
+            take, pending = pending[:n], pending[n:]
+            if take:
+                sched.admit(take, np.asarray(take, np.int32) % 300)
+            sched.run_epoch()
+        return sched
+
+    @pytest.mark.parametrize("method", ["adaptive", "erjs"])
+    def test_compacted_passes_equal_dense(self, method, monkeypatch):
+        order = np.random.default_rng(1).permutation(self.W)
+        # active counts: none, under K, exactly K, several chunks, all W
+        masks = []
+        for n in (0, 5, self.K, 19, self.W):
+            m = np.zeros(self.W, bool)
+            m[order[:n]] = True
+            masks.append(m)
+        got = {}
+        for chunk in (self.DENSE, self.K):
+            monkeypatch.setattr(ervs_mod, "LANE_CHUNK", chunk)
+            eng = self.engine(method)
+            state = self.lanes(eng)
+            got[chunk] = (self.passes(eng, state, masks), self.drive(eng))
+        deg = np.asarray(degrees_of(eng.graph, state.cur))
+        if method == "adaptive":  # the hub pass has lanes of its own
+            assert (deg[masks[-1]] >= self.HUB).any()
+            assert (deg[masks[-1]] < self.HUB).any()
+        (dense, d_run), (comp, c_run) = got[self.DENSE], got[self.K]
+        for m, d, c in zip(masks, dense, comp):
+            np.testing.assert_array_equal(d[0], c[0])  # picks, -2 inactive
+            assert (c[0][~m] == -2).all() and (c[0][m] >= 0).all()
+            assert (d[1], d[2]) == (c[1], c[2])  # trips, edges
+            assert c[3] <= d[3] == d[1] * self.W  # lane-trips
+        assert comp[0][3] == 0 and comp[1][3] < dense[1][3]
+        # whole runs: byte-identical paths, equal counters but lane-trips
+        np.testing.assert_array_equal(d_run.paths, c_run.paths)
+        lanes = {k: v for k, v in d_run.totals.items()
+                 if k != "ervs_lane_trips"}
+        assert lanes == {k: c_run.totals[k] for k in lanes}
+        assert d_run.totals["ervs_lane_trips"] == \
+            d_run.totals["ervs_trips"] * self.W
+        assert 0 < c_run.totals["ervs_lane_trips"] \
+            < d_run.totals["ervs_lane_trips"]
