@@ -32,10 +32,12 @@ path (eRVS over the *live* graph), so mutation costs one bitmap write
 up front.  Stale rows then enter a :class:`RebuildQueue` which the
 engine drains a budgeted few rows per scheduler epoch
 (``EngineConfig.rebuild_budget``): each drained row is re-baked from the
-current graph with the *same per-row float64 math* as a fresh build
+current graph with the *same float64 math* as a fresh build
 (:func:`rebuild_rows` is bit-identical to :func:`build_tables` row by
-row), and its validity bit flips back — the fallback is transient, never
-permanent.  ``WalkEngine.update_graph`` is the engine-level entry point.
+row: both run the bucketed builder :func:`_table_rows`, which equals the
+per-row definition :func:`_row_tables`), and its validity bit flips
+back — the fallback is transient, never permanent.
+``WalkEngine.update_graph`` is the engine-level entry point.
 """
 from __future__ import annotations
 
@@ -156,14 +158,16 @@ def _eval_static_weights(graph: CSRGraph, workload: Workload, params,
     return jnp.maximum(w, 0.0).astype(jnp.float32)
 
 
-def _vose_row(ww: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Textbook two-stack Vose alias construction for ONE row, float64.
-    Zero-total rows keep the neutral (alias=self-ish, prob=1) fill —
-    ``total[v] == 0`` masks them at draw time."""
+def _vose_row(ww: np.ndarray, tot: float) -> Tuple[np.ndarray, np.ndarray]:
+    """Textbook two-stack Vose alias construction for ONE row, float64,
+    normalised by ``tot`` (the row's float64 prefix-sum total, the value
+    its ITS ``total`` rounds from).  The per-row definition the bucketed
+    :func:`_table_rows` reproduces bit for bit.  Zero-total rows keep the
+    neutral (alias=self-ish, prob=1) fill — ``total[v] == 0`` masks them
+    at draw time."""
     d = ww.shape[0]
     alias = np.zeros(d, np.int32)
     prob = np.ones(d, np.float32)
-    tot = ww.sum()
     if d == 0 or tot <= 0:
         return alias, prob
     q = ww * d / tot
@@ -182,39 +186,141 @@ def _vose_row(ww: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return alias, prob
 
 
+def _row_tables(ww: np.ndarray
+                ) -> Tuple[np.ndarray, np.float32, np.ndarray, np.ndarray]:
+    """(cdf, total, alias, prob) of ONE row from its float64 weights: the
+    per-row definition of the tables (:func:`_table_rows` builds every
+    row at once and is bit-identical to it)."""
+    c = np.cumsum(ww)
+    tot = c[-1] if c.shape[0] else 0.0
+    alias, prob = _vose_row(ww, tot)
+    return c.astype(np.float32), np.float32(tot), alias, prob
+
+
+def _vose_lockstep(q: np.ndarray, d: np.ndarray, live: np.ndarray
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """Vose's construction run on every row of ``q`` ([n, D] float64,
+    row r's first ``d[r]`` columns) at once, one stack pop per row per
+    iteration.  Each row keeps its own small/large stacks (ascending at
+    the start, popped from the top) and does exactly the float64
+    operations :func:`_vose_row` does, in its order, so each row's
+    (alias, prob) is bit-identical to the per-row build.  Rows not
+    ``live`` (zero total) keep the neutral fill."""
+    n, D = q.shape
+    alias = np.zeros((n, D), np.int32)
+    prob = np.ones((n, D), np.float32)
+    cols = np.arange(D, dtype=np.int32)
+    valid = (cols[None, :] < d[:, None]) & live[:, None]
+    stacks, tops = [], []
+    for member in (valid & (q < 1.0), valid & (q >= 1.0)):
+        r, c = np.nonzero(member)  # row-major: ascending columns per row
+        depth = np.cumsum(member, axis=1, dtype=np.int32)[r, c] - 1
+        s = np.zeros((n, D), np.int32)
+        s[r, depth] = c
+        stacks.append(s.ravel())
+        tops.append(member.sum(axis=1).astype(np.int64))
+    sf, lf = stacks
+    stop, ltop = tops
+    qf, pf, af = q.ravel(), prob.ravel(), alias.ravel()
+    base = np.arange(n, dtype=np.int64) * D
+    act = np.nonzero((stop > 0) & (ltop > 0))[0]
+    while act.size:
+        b = base[act]
+        st = b + stop[act] - 1  # flat positions of the stack tops
+        lt = b + ltop[act] - 1
+        sm = b + sf[st]
+        lg = lf[lt]
+        qs = qf[sm]
+        pf[sm] = qs
+        af[sm] = lg
+        lg = b + lg
+        ql = qf[lg] - (1.0 - qs)
+        qf[lg] = ql
+        # lg goes back on a stack: onto the small one in sm's place (the
+        # large top shrinks), or it stays on top of the large one (the
+        # small top shrinks)
+        to_small = ql < 1.0
+        sf[st[to_small]] = lg[to_small] - b[to_small]
+        stop[act] -= ~to_small
+        ltop[act] -= to_small
+        act = act[(stop[act] > 0) & (ltop[act] > 0)]
+    # numerical leftovers on either stack: certain accept, alias self
+    for s, top in ((sf, stop), (lf, ltop)):
+        left = cols[None, :] < top[:, None]
+        r = np.nonzero(left)[0]
+        c = s.reshape(n, D)[left]
+        alias[r, c] = c
+    return alias, prob
+
+
+#: buckets with fewer rows are built row by row (:func:`_row_tables`): a
+#: lock-step iteration costs about what 16 rows' per-row iterations do
+_LOCKSTEP_MIN_ROWS = 16
+
+
+def _table_rows(w: np.ndarray, starts: np.ndarray, degs: np.ndarray
+                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """(cdf, total, alias, prob) of every row of the flat float64 weights
+    ``w`` (row v is ``w[starts[v]:starts[v] + degs[v]]``; per-edge outputs
+    at the same positions), bit-identical to :func:`_row_tables` row by
+    row.  Rows are bucketed by degree in powers of two; each bucket is
+    one zero-padded [rows, D] block whose float64 prefix sums give the
+    CDF and the totals, and whose alias tables :func:`_vose_lockstep`
+    builds in lock-step, so the Python-level work is O(buckets + longest
+    row) instead of O(rows + edges).  A bucket of fewer than
+    ``_LOCKSTEP_MIN_ROWS`` rows (the few longest rows of a power-law
+    graph) is built row by row, which costs less than its lock-step
+    iterations would."""
+    cdf = np.zeros(w.shape[0], np.float32)
+    total = np.zeros(degs.shape[0], np.float32)
+    alias = np.zeros(w.shape[0], np.int32)
+    prob = np.ones(w.shape[0], np.float32)
+    rows_nz = np.nonzero(degs > 0)[0]
+    bucket = np.frexp(degs[rows_nz].astype(np.float64))[1]
+    for k in np.unique(bucket):
+        rows = rows_nz[bucket == k]
+        if rows.size < _LOCKSTEP_MIN_ROWS:
+            for v in rows:
+                s = int(starts[v])
+                e = s + int(degs[v])
+                cdf[s:e], total[v], alias[s:e], prob[s:e] = \
+                    _row_tables(w[s:e])
+            continue
+        d = degs[rows].astype(np.int64)
+        D = int(d.max())
+        cols = np.arange(D, dtype=np.int64)
+        valid = cols[None, :] < d[:, None]
+        pos = starts[rows].astype(np.int64)[:, None] + \
+            np.minimum(cols[None, :], d[:, None] - 1)
+        ww = np.where(valid, w[pos], 0.0)
+        c = np.cumsum(ww, axis=1)
+        tot = c[np.arange(rows.size), d - 1]
+        live = tot > 0
+        q = ww * d[:, None] / np.where(live, tot, 1.0)[:, None]
+        a, p = _vose_lockstep(q, d, live)
+        flat = pos[valid]
+        cdf[flat] = c[valid]
+        total[rows] = tot
+        alias[flat] = a[valid]
+        prob[flat] = p[valid]
+    return cdf, total, alias, prob
+
+
 def _vose_build(w: np.ndarray, indptr: np.ndarray
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Vose alias tables for every CSR row (host-side, one-time
     preprocessing — not the per-step serial build the ALS baseline pays)."""
-    E = w.shape[0]
-    V = indptr.shape[0] - 1
-    alias = np.zeros(E, np.int32)
-    prob = np.ones(E, np.float32)
-    for v in range(V):
-        s, e = int(indptr[v]), int(indptr[v + 1])
-        if e > s:
-            alias[s:e], prob[s:e] = _vose_row(w[s:e].astype(np.float64))
+    indptr = np.asarray(indptr, np.int64)
+    _, _, alias, prob = _table_rows(np.asarray(w, np.float64), indptr[:-1],
+                                    np.diff(indptr))
     return alias, prob
-
-
-def _row_tables(ww: np.ndarray
-                ) -> Tuple[np.ndarray, np.float32, np.ndarray, np.ndarray]:
-    """(cdf, total, alias, prob) of ONE row from its float64 weights.
-
-    The single per-row constructor both :func:`build_tables` and
-    :func:`rebuild_rows` call — same math, same rounding, so a rebuilt
-    row is bit-identical to the row a fresh build would produce.
-    """
-    cdf = np.cumsum(ww).astype(np.float32)
-    total = cdf[-1] if cdf.shape[0] else np.float32(0.0)
-    alias, prob = _vose_row(ww)
-    return cdf, np.float32(total), alias, prob
 
 
 def build_tables(graph: CSRGraph, workload: Workload, params,
                  aligned: bool = True) -> PrecompTables:
     """One-time table build for a static workload (host-side, row-local
-    float64 accumulation so long rows keep full CDF precision).
+    float64 accumulation so long rows keep full CDF precision; rows
+    bucketed by degree and built a bucket at a time, :func:`_table_rows`).
 
     ``aligned`` additionally packs the tile-aligned [R, 128] kernel
     streams — required by the Pallas execution path, pure overhead
@@ -226,14 +332,7 @@ def build_tables(graph: CSRGraph, workload: Workload, params,
     if V and int(np.diff(indptr).max(initial=0)) >= (1 << 24):
         # alias offsets ride a float32 stream in the Pallas kernel layout
         raise ValueError("precomp tables require max degree < 2**24")
-    cdf = np.zeros(w.shape[0], np.float32)
-    total = np.zeros(V, np.float32)
-    alias = np.zeros(w.shape[0], np.int32)
-    prob = np.ones(w.shape[0], np.float32)
-    for v in range(V):
-        s, e = int(indptr[v]), int(indptr[v + 1])
-        if e > s:
-            cdf[s:e], total[v], alias[s:e], prob[s:e] = _row_tables(w[s:e])
+    cdf, total, alias, prob = _table_rows(w, indptr[:-1], np.diff(indptr))
     tables = PrecompTables(
         cdf=jnp.asarray(cdf),
         total=jnp.asarray(total),
@@ -294,12 +393,13 @@ def rebuild_rows(tables: PrecompTables, graph: CSRGraph, workload: Workload,
 
     Bit-identity contract (pinned by tests/test_rebuild.py): a rebuilt row
     equals the row :func:`build_tables` of the same graph would produce —
-    the per-edge weight evaluation and the per-row float64 table math are
-    the same code paths — so draining every stale row restores exactly the
-    fresh-build tables.  Rows are disjoint, so rebuild order is
-    irrelevant.  Updates both the flat arrays and (when present) the
-    tile-aligned kernel streams; all shapes are preserved, so the jitted
-    epoch closed over the *structure* never retraces.
+    the per-edge weight evaluation and the float64 table math
+    (:func:`_table_rows`) are the same code paths — so draining every
+    stale row restores exactly the fresh-build tables.  Rows are
+    disjoint, so rebuild order is irrelevant.  Updates both the flat
+    arrays and (when present) the tile-aligned kernel streams; all shapes
+    are preserved, so the jitted epoch closed over the *structure* never
+    retraces.
 
     ``scatter`` selects the write path (see :func:`_scatter_rows`): the
     default ``"donate"`` updates the tables in place — O(rows) per drain
@@ -333,15 +433,8 @@ def rebuild_rows(tables: PrecompTables, graph: CSRGraph, workload: Workload,
     else:
         w = np.zeros(0, np.float64)
 
-    new_cdf = np.zeros(edge_idx.size, np.float32)
-    new_total = np.zeros(nodes_arr.size, np.float32)
-    new_alias = np.zeros(edge_idx.size, np.int32)
-    new_prob = np.ones(edge_idx.size, np.float32)
-    for i in range(nodes_arr.size):
-        s, e = int(bounds[i]), int(bounds[i + 1])
-        if e > s:
-            (new_cdf[s:e], new_total[i],
-             new_alias[s:e], new_prob[s:e]) = _row_tables(w[s:e])
+    new_cdf, new_total, new_alias, new_prob = _table_rows(w, bounds[:-1],
+                                                          degs)
 
     out = dataclasses.replace(
         tables,

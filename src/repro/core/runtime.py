@@ -621,8 +621,10 @@ class WalkEngine:
             aligned = (resolve_precomp_exec(
                 self.config.precomp_exec) == "pallas"
                 or (self._fused_kind or "").startswith("precomp"))
-            self.precomp = precomp_mod.build_tables(
-                graph, workload, compiled_params(workload), aligned=aligned)
+            with jax.profiler.TraceAnnotation("engine.build_tables"):
+                self.precomp = precomp_mod.build_tables(
+                    graph, workload, compiled_params(workload),
+                    aligned=aligned)
         # stale rows queued by update_graph, drained a budgeted few per
         # scheduler epoch (config.rebuild_budget) / via drain_rebuilds()
         self.rebuild_queue = precomp_mod.RebuildQueue()
@@ -766,9 +768,10 @@ class WalkEngine:
             return
         from repro.kernels import megastep_kernel
         bmax = self._bake_bmax() if self._fused_kind == "rejection" else None
-        self._fused_streams = megastep_kernel.fused_streams(
-            self.graph, self.workload, bmax=bmax,
-            bucket_rows=self.overlay_active)
+        with jax.profiler.TraceAnnotation("engine.fused_streams"):
+            self._fused_streams = megastep_kernel.fused_streams(
+                self.graph, self.workload, bmax=bmax,
+                bucket_rows=self.overlay_active)
 
     # ------------------------------------------------------------ epoch fn
     def _make_epoch(self):

@@ -493,6 +493,7 @@ def make_streamed_epoch(program, params, *, kind: str, tile: int,
                 pltpu.SemaphoreType.DMA,
             ],
             interpret=interpret,
+            name=f"megastep_{kind}",
         )(*lanes, *streams, *ws_in)
         emitted, flags = outs[0][:, :W], outs[1][:, :W]
         cur, prev, stepc, alive = (o[:W] for o in outs[2:6])

@@ -74,12 +74,15 @@ def test_precomp_kernel_compiles(one_chip, kernel):
 
 
 # (kind, program, slots, epoch_len): the four fused regimes at the
-# smoke's offline widths, plus ppr_nibble's wstate leaf at served widths
+# smoke's offline widths, ppr_nibble's wstate leaf at served widths, and
+# the alias regime at the dw-alias-offline-pl20 cell's 4,096 slots and
+# 16-step epochs
 MEGASTEP_CELLS = [
     ("reservoir", "deepwalk", W_OFFLINE, STEPS),
     ("rejection", "deepwalk", W_OFFLINE, STEPS),
     ("precomp_its", "deepwalk", W_OFFLINE, STEPS),
     ("precomp_alias", "ppr_nibble", W_SERVED, SERVE_EPOCH),
+    ("precomp_alias", "deepwalk", 4096, 16),
 ]
 
 
@@ -110,4 +113,6 @@ def test_megastep_compiles(one_chip, kind, program, W, T):
         return epoch(state, types.SimpleNamespace(**tables), streams, T,
                      STEPS, 512)
 
-    _compile(run, state, tables, streams)
+    compiled = _compile(run, state, tables, streams)
+    # the kernel is named by its regime, so a trace can attribute it
+    assert f"megastep_{kind}" in compiled.as_text()
