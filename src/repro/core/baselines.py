@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.core.ctxutil import degrees_of, tile_ctx, eval_weights
 from repro.core.erjs import erjs_step
 from repro.core.types import Workload
-from repro.graphs.csr import CSRGraph
+from repro.graphs.csr import CSRGraph, search_depth
 
 
 def padded_weights(
@@ -39,9 +39,10 @@ def padded_weights(
     """Full-row transition weights, padded to [W, pad].  Returns (w, nbr, mask).
 
     ``wstate`` is the per-walker program state ([W]-leading leaves;
-    ``None`` for stateless programs)."""
+    ``None`` for stateless programs).  ``pad`` bounds every row, a
+    previous node's too: it sets the ``dist`` search's depth."""
     ctx, mask = tile_ctx(graph, workload, cur, prev, step,
-                         jnp.zeros_like(cur), pad)
+                         jnp.zeros_like(cur), pad, depth=search_depth(pad))
     w = eval_weights(workload, params, ctx, mask, wstate)
     return w, ctx.nbr, mask
 
@@ -96,7 +97,8 @@ def rjs_maxreduce_step(graph, workload: Workload, params, cur, prev, step, rng,
     exact_max = jnp.max(w, axis=1)
     nxt, fb, _ = erjs_step(graph, workload, params, cur, prev, step, rng,
                            bound=exact_max, trials_per_round=trials_per_round,
-                           max_rounds=max_rounds, wstate=wstate)
+                           max_rounds=max_rounds, wstate=wstate,
+                           depth=search_depth(pad))
     # exact max ⇒ acceptance ≥ 1/d; fall back to ITS on the (rare) unresolved
     its = its_step(graph, workload, params, cur, prev, step, rng, pad,
                    wstate=wstate)

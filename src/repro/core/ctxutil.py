@@ -2,7 +2,9 @@
 
 Builds EdgeCtx blocks of shape [W, T] (walkers × neighbor tile) from CSR,
 computing only the fields the workload declared it needs (dist is a binary
-search per edge; labels are a gather — both skipped when unused).
+search per edge, ``depth`` rounds deep; labels are a gather — both skipped
+when unused).  Callers pass ``depth = search_depth(bound)`` for a static
+bound on every row's length (``graphs/csr.py``), the engine's ``pad``.
 
 Rows are read through the ``row_starts`` / ``row_degs`` accessor protocol
 shared by ``CSRGraph`` and ``graphs.delta.OverlayGraph``, so every
@@ -17,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import EdgeCtx, Workload
-from repro.graphs.csr import CSRGraph, has_edge
+from repro.graphs.csr import CSRGraph, dist_code
 
 
 def degrees_of(graph: CSRGraph, v: jax.Array) -> jax.Array:
@@ -34,6 +36,8 @@ def tile_ctx(
     step: jax.Array,  # [W]
     tile_start: jax.Array,  # [] or [W] — offset within each row
     tile: int,
+    *,
+    depth: int,  # dist search rounds: search_depth of a row-length bound
 ) -> Tuple[EdgeCtx, jax.Array]:
     """Return (ctx[W, T], mask[W, T]) for neighbours [tile_start, tile_start+T)."""
     W = cur.shape[0]
@@ -50,9 +54,8 @@ def tile_ctx(
     else:
         label = jnp.zeros_like(nbr)
     if workload.needs_dist:
-        dist = jax.vmap(
-            lambda p, us: jax.vmap(lambda u: _dist_code(graph, p, u))(us)
-        )(prev, nbr)
+        dist = jax.vmap(lambda p, us: jax.vmap(
+            lambda u: _dist_code(graph, p, u, depth))(us))(prev, nbr)
     else:
         dist = jnp.ones_like(nbr)
     ctx = EdgeCtx(
@@ -76,6 +79,8 @@ def single_edge_ctx(
     prev: jax.Array,  # [W]
     step: jax.Array,  # [W]
     offset: jax.Array,  # [W] — neighbour offset within the row (one trial)
+    *,
+    depth: int,  # as tile_ctx's
 ) -> Tuple[EdgeCtx, jax.Array]:
     """EdgeCtx for exactly one candidate edge per walker (rejection trials)."""
     deg_cur = degrees_of(graph, cur)
@@ -87,7 +92,8 @@ def single_edge_ctx(
     h = jnp.where(valid, graph.h[pos], 0.0) if workload.weighted else jnp.where(valid, 1.0, 0.0)
     label = jnp.where(valid, graph.labels[pos], -1) if workload.needs_labels else jnp.zeros_like(nbr)
     if workload.needs_dist:
-        dist = jax.vmap(lambda p, u: _dist_code(graph, p, u))(prev, nbr)
+        dist = jax.vmap(
+            lambda p, u: _dist_code(graph, p, u, depth))(prev, nbr)
     else:
         dist = jnp.ones_like(nbr)
     ctx = EdgeCtx(
@@ -97,10 +103,9 @@ def single_edge_ctx(
     return ctx, valid
 
 
-def _dist_code(graph: CSRGraph, v_prev: jax.Array, u: jax.Array) -> jax.Array:
-    from repro.graphs.csr import dist_code
-
-    return dist_code(graph, v_prev, jnp.maximum(u, 0))
+def _dist_code(graph: CSRGraph, v_prev: jax.Array, u: jax.Array,
+               depth: int) -> jax.Array:
+    return dist_code(graph, v_prev, jnp.maximum(u, 0), depth)
 
 
 def eval_weights(workload: Workload, params, ctx: EdgeCtx, mask: jax.Array,

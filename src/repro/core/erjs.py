@@ -28,11 +28,12 @@ import jax.numpy as jnp
 
 from repro.core.ctxutil import degrees_of, single_edge_ctx
 from repro.core.types import Workload
-from repro.graphs.csr import CSRGraph
+from repro.graphs.csr import INT32_DEPTH, CSRGraph
 from repro.kernels.prng import threefry_seeds, trial_uniform
 
 
-@partial(jax.jit, static_argnames=("workload", "params", "trials_per_round", "max_rounds"))
+@partial(jax.jit, static_argnames=("workload", "params", "trials_per_round",
+                                   "max_rounds", "depth"))
 def erjs_step(
     graph: CSRGraph,
     workload: Workload,
@@ -46,8 +47,12 @@ def erjs_step(
     max_rounds: int = 16,
     active: Optional[jax.Array] = None,
     wstate=None,
+    depth: int = INT32_DEPTH,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (next [W], needs_fallback [W] bool, rounds_used [] int32).
+
+    ``depth`` is the ``dist`` search's (``graphs/csr.py`` ``search_depth``
+    of a bound on every row's length); the default covers any int32 row.
 
     next = -2 for inactive walkers, -1 for zero-degree rows.
     needs_fallback marks walkers unresolved after max_rounds (engine runs
@@ -71,7 +76,8 @@ def erjs_step(
             # propose X ~ Uniform(N(v)) — the uniform proposal q of Eq. 5
             offset = jnp.minimum((u_idx * deg.astype(jnp.float32)).astype(jnp.int32),
                                  jnp.maximum(deg - 1, 0))
-            ctx, valid = single_edge_ctx(graph, workload, cur, prev, step, offset)
+            ctx, valid = single_edge_ctx(graph, workload, cur, prev, step,
+                                         offset, depth=depth)
             flat = jax.vmap(workload.edge_weight,
                             in_axes=(0, None, 0))(ctx, params, wstate)
             w = jnp.where(valid, jnp.maximum(flat, 0.0), 0.0)

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from repro.core.ctxutil import degrees_of as degrees_of_cached, eval_weights, tile_ctx
 from repro.core.types import Workload
-from repro.graphs.csr import CSRGraph
+from repro.graphs.csr import INT32_DEPTH, CSRGraph, search_depth
 from repro.kernels.prng import threefry_seeds, tile_uniforms
 
 NEG_INF = jnp.float32(-jnp.inf)
@@ -70,6 +70,16 @@ def tile_pass(graph: CSRGraph, cur: jax.Array, active: jax.Array, tile: int,
         trips = jnp.minimum(trips, max_tiles)
     edges = jnp.sum(jnp.minimum(deg_act, trips * tile), dtype=jnp.int32)
     return trips.astype(jnp.int32), edges
+
+
+def pass_depth(tile: int, max_tiles: Optional[int]) -> int:
+    """Depth of the ``dist`` search in a pass of at most ``max_tiles``
+    trips: ``max_tiles · tile`` (the engine's ``pad`` for a power-of-two
+    tile) bounds every row, a previous node's too; uncapped, any int32
+    row."""
+    if max_tiles is None:
+        return INT32_DEPTH
+    return search_depth(max_tiles * tile)
 
 
 def compact_lanes(pass_fn: Callable, lanes, active: jax.Array,
@@ -157,10 +167,13 @@ def ervs_step(
     inactive walkers return -2 (untouched sentinel for the engine to merge).
     ``wstate`` is the per-walker program state fed to ``get_weight``
     (WalkProgram contract); ``None`` for stateless programs.
+    ``max_tiles · tile`` must bound every row: it sets the depth of the
+    ``dist`` search (:func:`pass_depth`).
     """
     W = cur.shape[0]
     if active is None:
         active = jnp.ones((W,), bool)
+    depth = pass_depth(tile, max_tiles)
     # dynamic trip count: tiles needed by the *active* partition only — when
     # the cost model sends every high-degree walker to eRJS, the eRVS pass
     # shrinks accordingly (fori_loop with a traced bound lowers to while).
@@ -169,7 +182,8 @@ def ervs_step(
     def body(t, carry):
         best_lk, best_nbr = carry
         ctx, mask = tile_ctx(graph, workload, cur, prev, step,
-                             jnp.full((W,), t * tile, jnp.int32), tile)
+                             jnp.full((W,), t * tile, jnp.int32), tile,
+                             depth=depth)
         w = eval_weights(workload, params, ctx, mask, wstate)
         # counter-based per-(walker, tile) uniforms — the "jumping RNG" idiom:
         # no sequential stream to advance, so tiles are independent.
@@ -213,16 +227,19 @@ def ervs_jump_step(
     block-jump kernel (kernels/ervs_kernel.py) skips whole *blocks*; it and
     its reference ``kernels/ref.py:ervs_select_ref`` count the draws they
     consume, the Fig. 12a JUMP ablation's statistics source.
+    ``max_tiles`` is as in :func:`ervs_step`.
     """
     W = cur.shape[0]
     if active is None:
         active = jnp.ones((W,), bool)
+    depth = pass_depth(tile, max_tiles)
     needed, _ = tile_pass(graph, cur, active, tile, max_tiles)
 
     def body(t, carry):
         lk_max, nbr_best, thresh, cumw = carry
         ctx, mask = tile_ctx(graph, workload, cur, prev, step,
-                             jnp.full((W,), t * tile, jnp.int32), tile)
+                             jnp.full((W,), t * tile, jnp.int32), tile,
+                             depth=depth)
         w = eval_weights(workload, params, ctx, mask, wstate)  # [W, tile]
         w = jnp.where(active[:, None], w, 0.0)
         is_first = lk_max == NEG_INF  # lane not initialised yet

@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.core.ctxutil import degrees_of
 from repro.core.types import EdgeCtx, Workload
-from repro.graphs.csr import CSRGraph
+from repro.graphs.csr import INT32_DEPTH, CSRGraph
 from repro.graphs.delta import host_row_layout
 # Threefry counter salts and the key derivation live in kernels/prng.py,
 # shared with kernels/precomp_kernel.py, the mega-step kernel and the
@@ -640,31 +640,20 @@ class RebuildQueue:
 
 
 # ----------------------------------------------------------- jnp selectors
-def search_depth(max_degree: int) -> int:
-    """Binary-search iterations guaranteed to converge for rows with at
-    most ``max_degree`` neighbours (+1 slack).  Must be computed from a
-    *static* bound (e.g. ``SamplerContext.pad``) — inside a jitted epoch
-    the graph arrays are tracers, so the depth cannot be derived there.
-    Extra iterations past convergence are no-ops (the ``lo < hi`` guard),
-    which is why any sufficient depth matches the Pallas kernel's
-    run-to-convergence ``while_loop`` bit for bit."""
-    return int(np.ceil(np.log2(max(max_degree, 1) + 1))) + 1
-
-
 def its_select(graph: CSRGraph, tables: PrecompTables, cur: jax.Array,
                rng: jax.Array, *, active: jax.Array,
-               depth: int = 32) -> jax.Array:
+               depth: int = INT32_DEPTH) -> jax.Array:
     """O(log d) inverse-transform draw from the baked CDF.
 
     ``u·total`` → fixed-depth binary search for the first row offset whose
     inclusive prefix exceeds the target (zero-weight neighbours share the
     previous prefix value, so they can never be landed on).  ``depth``
-    bounds the halvings (see :func:`search_depth`; the default 32 covers
-    any int32 degree).  The uniform comes from the counter-based Threefry
-    stream (:func:`threefry_seeds` + ``ITS_SALT``) — the same draw the
-    Pallas ``its_search`` kernel makes, so both paths pick the same
-    offset.  Returns next nodes [W]; -1 for inactive / empty /
-    zero-total lanes.
+    bounds the halvings (see :func:`repro.graphs.csr.search_depth`; the
+    default covers any int32 degree).  The uniform comes from the
+    counter-based Threefry stream (:func:`threefry_seeds` +
+    ``ITS_SALT``) — the same draw the Pallas ``its_search`` kernel
+    makes, so both paths pick the same offset.  Returns next nodes [W];
+    -1 for inactive / empty / zero-total lanes.
     """
     E = graph.num_edges
     deg = degrees_of(graph, cur)

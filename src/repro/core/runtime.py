@@ -60,7 +60,7 @@ from repro.core.samplers import (PRECOMP_EXEC_CHOICES, SamplerContext,
 from repro.core.types import (EdgeCtx, StepStats, WalkerState, WalkProgram,
                               Workload, from_workload)
 from repro.distributed import sharding as shd
-from repro.graphs.csr import CSRGraph
+from repro.graphs.csr import CSRGraph, search_depth
 from repro.graphs import node_stats
 from repro.graphs.delta import GraphDelta, UpdateReport, host_row_layout
 # DMA block size of the mega-step kernel (kernels/ref.py is jnp-only —
@@ -668,6 +668,14 @@ class WalkEngine:
         self._fused_streams = None
         self._refresh_fused_streams()
 
+    @property
+    def dist_depth(self) -> int:
+        """Rounds of the staged path's ``dist`` search: ``search_depth``
+        of the padded row length ``pad``, so it moves only with ``pad``
+        (``StepStats.dist_searches`` times this is the search's gather
+        rounds)."""
+        return search_depth(self.pad)
+
     # ------------------------------------------------------ fused planning
     @property
     def step_exec_resolved(self) -> str:
@@ -867,7 +875,8 @@ class WalkEngine:
                               stale_served=sel.stale_served,
                               ervs_trips=sel.ervs_trips,
                               ervs_edges=sel.ervs_edges,
-                              ervs_lane_trips=sel.ervs_lane_trips)
+                              ervs_lane_trips=sel.ervs_lane_trips,
+                              dist_searches=sel.dist_searches)
             return new_state, jnp.where(stepped, nxt, -1), stats
 
         # the function's name is the XLA module's (jit_epoch_staged): the
@@ -1182,14 +1191,15 @@ class WalkEngine:
     def _set_pad(self, max_degree: int, *, floor: int = 0) -> None:
         # identical to the __init__ formula — the fuzzer's fresh-build
         # oracle relies on pad/max_tiles (and hence the eRVS tile-trip
-        # bound and ITS search depth) matching a from-scratch engine.
-        # ``floor`` keeps the pad monotone across overlay applies (sticky
-        # pow2 bucketing, so a mutation burst reuses the jitted epoch
-        # instead of flapping between pad shapes); oversizing is
-        # bit-neutral — ITS search iterations past convergence are no-ops,
-        # eRVS tile trips are clamped by live degrees, and padded-row
-        # weight baselines mask the extra lanes.  compact() calls with
-        # the default floor, restoring the exact fresh-build formula.
+        # bound and the ITS and dist search depths) matching a
+        # from-scratch engine.  ``floor`` keeps the pad monotone across
+        # overlay applies (sticky pow2 bucketing, so a mutation burst
+        # reuses the jitted epoch instead of flapping between pad
+        # shapes); oversizing is bit-neutral — ITS and dist search
+        # iterations past convergence are no-ops, eRVS tile trips are
+        # clamped by live degrees, and padded-row weight baselines mask
+        # the extra lanes.  compact() calls with the default floor,
+        # restoring the exact fresh-build formula.
         self.max_degree = int(max_degree)
         self.pad = max(1 << (self.max_degree - 1).bit_length(),
                        self.config.tile, int(floor))

@@ -59,7 +59,7 @@ from repro.core.ervs import (NEG_INF, _log_keys, _tile_uniforms,
                              compact_lanes, ervs_jump_step, ervs_step,
                              tile_pass)
 from repro.core.types import EdgeCtx, WalkerState
-from repro.graphs.csr import dist_code
+from repro.graphs.csr import dist_code, search_depth
 
 
 # ---------------------------------------------------------------- metadata
@@ -109,6 +109,10 @@ class Selection:
         default_factory=lambda: jnp.int32(0))
     ervs_lane_trips: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
+    # candidate dist searches run: eRVS lane-slots plus eRJS trials
+    # (StepStats.dist_searches)
+    dist_searches: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
     # sampler-owned cross-step state; the engine stores it in
     # WalkerState.carry for the next step (None = carry nothing)
     carry: Any = None
@@ -137,6 +141,12 @@ class SamplerContext:
     # devices the slot axis is block-sharded over: the reservoir passes
     # compact their lanes within each device's block (compact_lanes)
     shards: int = 1
+
+    @property
+    def dist_depth(self) -> int:
+        """Rounds of the ``dist`` search (and of the jnp ITS search):
+        ``pad`` bounds every row."""
+        return search_depth(self.pad)
 
     def bound_inputs(self, state: WalkerState) -> fc.BoundInputs:
         vs = jnp.maximum(state.cur, 0)
@@ -240,14 +250,25 @@ def available_samplers() -> Tuple[str, ...]:
 
 
 # ------------------------------------------------------------- reservoirs
+def _searches(ctx, candidates) -> jax.Array:
+    """``dist`` searches of ``candidates`` edges: one each where the
+    workload reads ``dist``, none otherwise."""
+    if ctx.workload.needs_dist:
+        return candidates
+    return jnp.int32(0)
+
+
 def _dense_pass(ctx, state, active, nxt) -> Selection:
     """Selection of a ``[W, tile]`` tile-loop pass over every lane."""
     trips, edges = tile_pass(ctx.graph, state.cur, active, ctx.config.tile,
                              ctx.max_tiles)
     zero = jnp.int32(0)
+    lane_trips = trips * state.cur.shape[0]
     return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
                      ervs_trips=trips, ervs_edges=edges,
-                     ervs_lane_trips=trips * state.cur.shape[0])
+                     ervs_lane_trips=lane_trips,
+                     dist_searches=_searches(ctx,
+                                             lane_trips * ctx.config.tile))
 
 
 def _compacted(sampler, ctx, state, rng, active):
@@ -303,21 +324,23 @@ class RejectionComponent(abc.ABC):
     @abc.abstractmethod
     def propose(self, ctx: SamplerContext, state: WalkerState,
                 rng: jax.Array, bound: jax.Array, active: jax.Array
-                ) -> Tuple[jax.Array, jax.Array]:
-        """Return (next_nodes [W], needs_fallback [W] bool)."""
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """Return (next_nodes [W], needs_fallback [W] bool, trials []
+        int32: the candidate edges evaluated, over every lane)."""
 
 
 class ERJSRejection(RejectionComponent):
     """eRJS — bound-based rejection trials (paper §3.3, Eqs. 5–8)."""
 
     def propose(self, ctx, state, rng, bound, active):
-        nxt, fb, _ = erjs_step(
+        nxt, fb, rounds = erjs_step(
             ctx.graph, ctx.workload, ctx.params,
             state.cur, state.prev, state.step, rng, bound=bound,
             trials_per_round=ctx.config.rjs_trials,
             max_rounds=ctx.config.rjs_max_rounds, active=active,
-            wstate=state.wstate)
-        return nxt, fb
+            wstate=state.wstate, depth=ctx.dist_depth)
+        # every round runs its trials on every lane of the batch
+        return nxt, fb, rounds * ctx.config.rjs_trials * state.cur.shape[0]
 
 
 # -------------------------------------------------------- selector policies
@@ -441,8 +464,8 @@ class PartitionedSampler(Sampler):
         with jax.named_scope("cost_model"):
             want_rjs = self.policy(ctx, state, est, deg, rest, rng) & rest
         with jax.named_scope("erjs"):
-            nxt_rjs, fb = self.rejection.propose(ctx, state, rng,
-                                                 est.bound_max, want_rjs)
+            nxt_rjs, fb, trials = self.rejection.propose(
+                ctx, state, rng, est.bound_max, want_rjs)
         # reservoir partition = lanes the policy kept + rejection fallbacks
         res_active = rest & ((~want_rjs) | fb)
         nxt_res, trips, edges, lane_trips = self._reservoir_select(
@@ -468,6 +491,8 @@ class PartitionedSampler(Sampler):
             stale_served=jnp.sum(
                 (stale_pre & (nxt >= 0)).astype(jnp.int32)),
             ervs_trips=trips, ervs_edges=edges, ervs_lane_trips=lane_trips,
+            dist_searches=_searches(
+                ctx, lane_trips * ctx.config.tile + trials),
         )
 
     def fused_kind(self, *, usable, has_precomp):
@@ -584,7 +609,7 @@ def precomp_table_select(ctx: SamplerContext, state: WalkerState,
     if kind == "its":
         return precomp_mod.its_select(
             graph, tables, state.cur, rng, active=active,
-            depth=precomp_mod.search_depth(ctx.pad))
+            depth=ctx.dist_depth)
     return precomp_mod.alias_select(graph, tables, state.cur, rng,
                                     active=active)
 
@@ -616,7 +641,8 @@ class _PrecompBase(Sampler):
             return Selection(next_nodes=dyn.next_nodes, rjs_served=zero,
                              fallbacks=zero, ervs_trips=dyn.ervs_trips,
                              ervs_edges=dyn.ervs_edges,
-                             ervs_lane_trips=dyn.ervs_lane_trips)
+                             ervs_lane_trips=dyn.ervs_lane_trips,
+                             dist_searches=dyn.dist_searches)
         ok = active & ctx.precomp.row_valid(state.cur)
         nxt_pre = precomp_table_select(ctx, state, rng, ok, kind=self.kind)
         stale = active & ~ok
@@ -631,7 +657,8 @@ class _PrecompBase(Sampler):
             stale_served=jnp.sum(
                 (stale & (dyn.next_nodes >= 0)).astype(jnp.int32)),
             ervs_trips=dyn.ervs_trips, ervs_edges=dyn.ervs_edges,
-            ervs_lane_trips=dyn.ervs_lane_trips)
+            ervs_lane_trips=dyn.ervs_lane_trips,
+            dist_searches=dyn.dist_searches)
 
     def fused_kind(self, *, usable, has_precomp):
         # With baked tables the kernel serves the table regime (stale rows
@@ -751,7 +778,8 @@ class InterleavedSampler(Sampler):
             nbr0, h0, label0 = nbr_f, h_f, label_f
         if wl.needs_dist:
             dist0 = jax.vmap(lambda p, us: jax.vmap(
-                lambda u: dist_code(graph, p, jnp.maximum(u, 0)))(us)
+                lambda u: dist_code(graph, p, jnp.maximum(u, 0),
+                                    ctx.dist_depth))(us)
             )(prev, nbr0)
         else:
             dist0 = jnp.ones_like(nbr0)
@@ -782,7 +810,8 @@ class InterleavedSampler(Sampler):
         def body(t, carry):
             best_lk, best_nbr = carry
             tctx, tmask = tile_ctx(graph, wl, cur, prev, step,
-                                   jnp.full((W,), t * tile, jnp.int32), tile)
+                                   jnp.full((W,), t * tile, jnp.int32), tile,
+                                   depth=ctx.dist_depth)
             w = eval_weights(wl, ctx.params, tctx, tmask, state.wstate)
             u = _tile_uniforms(rng, t, (W, tile))
             lk = jnp.where(tmask & active[:, None], _log_keys(u, w), NEG_INF)
@@ -802,7 +831,10 @@ class InterleavedSampler(Sampler):
         carry = PrefetchTile(node=nxt_node, nbr=pn_nbr, h=pn_h,
                              label=pn_label)
         zero = jnp.int32(0)
+        # tile 0 runs whatever the trip count
+        tiles = jnp.maximum(needed, 1).astype(jnp.int32)
         return Selection(next_nodes=nxt, rjs_served=zero, fallbacks=zero,
+                         dist_searches=_searches(ctx, tiles * W * tile),
                          carry=carry)
 
 

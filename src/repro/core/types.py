@@ -347,6 +347,14 @@ class StepStats:
         default_factory=lambda: jnp.int32(0))
     ervs_lane_trips: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
+    # candidate dist searches run, for workloads that read dist (0
+    # otherwise): the eRVS lane-slots (ervs_lane_trips · tile, the
+    # interleaved sampler's tile loop likewise) plus the eRJS trials
+    # (rounds · trials per round · slots).  Times
+    # WalkEngine.dist_depth, the gather rounds the search costs.  The
+    # padded-row baselines and the fused mega-step report 0.
+    dist_searches: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
 
     def host_totals(self) -> dict:
         """Each counter summed to a host int, keyed by field name.
@@ -375,4 +383,5 @@ class StepStats:
                    fallbacks=count(cls.FALLBACK),
                    precomp_served=count(cls.PRECOMP),
                    stale_served=count(cls.STALE),
-                   ervs_trips=zero, ervs_edges=zero, ervs_lane_trips=zero)
+                   ervs_trips=zero, ervs_edges=zero, ervs_lane_trips=zero,
+                   dist_searches=zero)

@@ -3,8 +3,11 @@
 Design notes
 ------------
 * ``indices`` is sorted within each row — this makes ``dist(v', u)`` (the
-  Node2Vec/2nd-PR "is u a neighbour of the previous node" test) a fixed-depth
-  binary search (:func:`has_edge`), vectorisable with ``vmap``.
+  Node2Vec/2nd-PR "is u a neighbour of the previous node" test) a binary
+  search (:func:`has_edge`), vectorisable with ``vmap``.  Its depth is
+  static: :func:`search_depth` of a bound on every row's length (the
+  engine's padded row length ``pad``), since each round is one gather of
+  every candidate, converged or not.
 * ``node_stats`` is the JAX equivalent of the code Flexi-Compiler *generates*
   for ``preprocess()`` (paper Fig. 9d): per-node h_MAX / h_MIN / h_SUM /
   h_MEAN pointers, computed with segment reductions.
@@ -159,41 +162,65 @@ def neighbor_slice(graph: CSRGraph, v: jax.Array, width: int):
     return nbr, hh, ll, mask
 
 
-@partial(jax.jit, static_argnames=())
-def has_edge(graph: CSRGraph, v: jax.Array, u: jax.Array) -> jax.Array:
+def search_depth(max_degree: int) -> int:
+    """Binary-search iterations guaranteed to converge for rows with at
+    most ``max_degree`` neighbours (+1 slack).  Must be computed from a
+    *static* bound (e.g. ``SamplerContext.pad``) — inside a jitted epoch
+    the graph arrays are tracers, so the depth cannot be derived there.
+    Extra iterations past convergence are no-ops (the ``lo < hi`` guard),
+    which is why any sufficient depth gives the same answer bit for bit
+    (and matches the Pallas kernels' run-to-convergence ``while_loop``)."""
+    return int(np.ceil(np.log2(max(max_degree, 1) + 1))) + 1
+
+
+#: :func:`search_depth` of the longest int32 row: the depth that needs no
+#: bound on the rows
+INT32_DEPTH = 32
+
+
+@partial(jax.jit, static_argnames=("depth",))
+def has_edge(graph: CSRGraph, v: jax.Array, u: jax.Array,
+             depth: int = INT32_DEPTH) -> jax.Array:
     """True iff edge (v, u) exists.  Fixed-depth binary search on the sorted
     row ``indices[indptr[v]:indptr[v+1]]`` — vectorise with vmap over (v, u).
 
-    Handles v == -1 (no previous node yet) by returning False.
+    ``depth`` must cover v's row: :func:`search_depth` of any bound on the
+    row lengths (the default covers every int32 row).  Each iteration is
+    one gather whether or not the search has converged, so the depth is
+    the search's cost.  Handles v == -1 (no previous node yet) by
+    returning False.
     """
     valid = v >= 0
     vs = jnp.maximum(v, 0)
     lo = graph.row_starts(vs)
-    end = lo + graph.row_degs(vs)
-    hi = end
+    hi = lo + graph.row_degs(vs)
 
     def body(_, carry):
-        lo, hi = carry
+        lo, hi, found = carry
         mid = (lo + hi) // 2
         mid_val = graph.indices[jnp.clip(mid, 0, graph.num_edges - 1)]
-        go_right = jnp.logical_and(mid_val < u, lo < hi)
+        live = lo < hi
+        # a search that finds u probes it: the first entry >= u is where
+        # hi last moved, and hi only moves to a probed mid
+        found = found | (live & (mid_val == u))
+        go_right = jnp.logical_and(mid_val < u, live)
         new_lo = jnp.where(go_right, mid + 1, lo)
-        new_hi = jnp.where(jnp.logical_or(go_right, lo >= hi), hi, mid)
-        return (new_lo, new_hi)
+        new_hi = jnp.where(jnp.logical_or(go_right, ~live), hi, mid)
+        return (new_lo, new_hi, found)
 
-    # ceil(log2(E)) iterations always suffice; use 32 for safety at int32.
-    lo, hi = jax.lax.fori_loop(0, 32, body, (lo, hi))
-    found = jnp.logical_and(lo < end,
-                            graph.indices[jnp.clip(lo, 0, graph.num_edges - 1)] == u)
+    _, _, found = jax.lax.fori_loop(
+        0, depth, body, (lo, hi, jnp.zeros(jnp.shape(lo), bool)))
     return jnp.logical_and(valid, found)
 
 
-def dist_code(graph: CSRGraph, v_prev: jax.Array, u: jax.Array) -> jax.Array:
+def dist_code(graph: CSRGraph, v_prev: jax.Array, u: jax.Array,
+              depth: int = INT32_DEPTH) -> jax.Array:
     """Node2Vec's dist(v', u) ∈ {0, 1, 2}: 0 if u == v', 1 if (v'→u) ∈ E,
     else 2.  v' == -1 (first step) returns 1 ("stay neutral"), matching the
-    usual first-step semantics of Node2Vec implementations.
+    usual first-step semantics of Node2Vec implementations.  ``depth`` is
+    :func:`has_edge`'s.
     """
     is_prev = u == v_prev
-    connected = has_edge(graph, v_prev, u)
+    connected = has_edge(graph, v_prev, u, depth)
     d = jnp.where(is_prev, 0, jnp.where(connected, 1, 2))
     return jnp.where(v_prev < 0, 1, d).astype(jnp.int32)

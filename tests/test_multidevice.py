@@ -87,9 +87,12 @@ for devices in (1, 2):
 one, two = runs[1], runs[2]
 np.testing.assert_array_equal(one.paths, two.paths)
 for k in one.totals:
-    if k != "ervs_lane_trips":
+    if k not in ("ervs_lane_trips", "dist_searches"):
         assert one.totals[k] == two.totals[k], (k, one.totals, two.totals)
 assert 0 < two.totals["ervs_lane_trips"] < two.totals["ervs_trips"] * 16
+# the dist searches follow the lane-trips; the eRJS trials' stay equal
+assert len({s.totals["dist_searches"] - s.totals["ervs_lane_trips"] * 64
+            for s in (one, two)}) == 1
 hlo = eng._epoch_fn.lower(
     two.state, two.tables, two.graph_view, two.stats_view, epoch_len=3,
     num_steps=9, pad=two.pad_view, max_tiles=two.max_tiles_view,

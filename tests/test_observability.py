@@ -184,6 +184,10 @@ def test_lane_trips_match_a_numpy_recount(monkeypatch):
         *_, lanes = recount(eng, sched.paths, slot_q, step0)
         assert stats["ervs_lane_trips"] == lanes
     np.testing.assert_array_equal(dense.paths, sched.paths)
+    # the dist searches follow the lane-trips; the eRJS trials' stay equal
+    trials = [s.totals.pop("dist_searches") - s.totals["ervs_lane_trips"]
+              * TILE for s in (dense, sched)]
+    assert trials[0] == trials[1] > 0
     lane_trips = sched.totals.pop("ervs_lane_trips")
     assert 0 < lane_trips < sched.totals["ervs_trips"] * SLOTS
     assert dense.totals.pop("ervs_lane_trips") \
@@ -218,10 +222,14 @@ for devices, shards in ((None, 1), (4, 4)):
         *_, lanes = t.recount(eng, sched.paths, slot_q, step0, shards)
         assert stats["ervs_lane_trips"] == lanes, (shards, stats, lanes)
     np.testing.assert_array_equal(one.paths, sched.paths)
+    # the dist searches follow the lane-trips; the eRJS trials' stay equal
+    assert sched.totals.pop("dist_searches") \
+        - sched.totals["ervs_lane_trips"] * t.TILE \
+        == one.totals["dist_searches"] - one.totals["ervs_lane_trips"] * t.TILE
     lane_trips = sched.totals.pop("ervs_lane_trips")
     assert 0 < lane_trips < sched.totals["ervs_trips"] * t.SLOTS
     rest = dict(one.totals)
-    del rest["ervs_lane_trips"]
+    del rest["ervs_lane_trips"], rest["dist_searches"]
     assert rest == sched.totals, (shards, rest, sched.totals)
 print("COUNTERS-X4-OK", one.totals)
 """

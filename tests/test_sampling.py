@@ -400,10 +400,14 @@ class TestLaneCompaction:
             assert c[3] <= d[3] == d[1] * self.W  # lane-trips
         assert comp[0][3] == 0 and comp[1][3] < dense[1][3]
         # whole runs: byte-identical paths, equal counters but lane-trips
+        # and the dist searches they run (the eRJS trials' stay equal)
         np.testing.assert_array_equal(d_run.paths, c_run.paths)
         lanes = {k: v for k, v in d_run.totals.items()
-                 if k != "ervs_lane_trips"}
+                 if k not in ("ervs_lane_trips", "dist_searches")}
         assert lanes == {k: c_run.totals[k] for k in lanes}
+        trials = [r.totals["dist_searches"] - r.totals["ervs_lane_trips"] * 16
+                  for r in (d_run, c_run)]
+        assert trials[0] == trials[1] > 0
         assert d_run.totals["ervs_lane_trips"] == \
             d_run.totals["ervs_trips"] * self.W
         assert 0 < c_run.totals["ervs_lane_trips"] \
